@@ -1858,9 +1858,9 @@ def warm_plan(
     the first post-swap batch runs the one-dispatch fast path instead of
     re-ratcheting.
 
-    Warming is a pure performance action: it never changes any query
-    result, and callers treat failures as non-fatal (a plan that could not
-    be warmed still answers correctly, just colder).
+    Warming never changes any query result.  It runs the same kernels a
+    query would, so a failure here is raised to the caller: a plan that
+    cannot warm could not answer either.
     """
     if spec_from is not None:
         pack.adopt_spec(spec_from)

@@ -6,8 +6,11 @@ device can run the same alpha-window pruning locally; a query's window touches
 at most a contiguous run of devices, and devices outside it prune everything at
 block level (zero matmuls on a real TPU via the Pallas kernel skip).
 
-Fixed-shape outputs only (counts / per-shard top-k) — exact variable-length
-extraction stays a host-side operation, as in the single-device API.
+Device-resident sharding covers fixed-shape outputs only (`shard_index` +
+the shard_map count / per-shard top-k functions).  The exact CSR entry
+(`query_radius_csr_sharded`) uses the mesh only to split the index into
+per-shard segments: its packed plan and every kernel launch live on one
+device.
 """
 from __future__ import annotations
 
@@ -63,8 +66,13 @@ def shard_index(index: _snn.SNNIndex, mesh: Mesh, axis: str = "data", block: int
 
 
 def _local_filter(xs, alphas, half_norms, xq, aq, r, thresh):
-    """Per-shard masked halved distances (m, n_local); +BIG where pruned."""
-    dhalf = half_norms[None, :] - xq @ xs.T
+    """Per-shard masked halved distances (m, n_local); +BIG where pruned.
+
+    The contraction runs at HIGHEST precision: a default-precision f32
+    matmul may take bf16 passes on a TPU, which moves the radius boundary.
+    """
+    dhalf = half_norms[None, :] - jnp.matmul(
+        xq, xs.T, precision=jax.lax.Precision.HIGHEST)
     inwin = jnp.abs(alphas[None, :] - aq[:, None]) <= r[:, None]
     keep = inwin & (dhalf <= thresh[:, None])
     big = jnp.asarray(jnp.finfo(dhalf.dtype).max / 8, dhalf.dtype)

@@ -138,8 +138,11 @@ def _extra_components(xs: np.ndarray, v1: np.ndarray, alphas: np.ndarray,
         for _ in range(k - 1):
             vc = _power_iteration(resid, n_iter=n_iter)
             vs.append(np.asarray(vc))
-            # project the ORIGINAL data: exact orthogonality is not required
-            projs.append(np.asarray(xj @ vc))
+            # project the ORIGINAL data: exact orthogonality is not required.
+            # HIGHEST: these projections meet the numpy-projected queries in
+            # the box test, so they must be f32-accurate on every platform
+            projs.append(np.asarray(jnp.matmul(
+                xj, vc, precision=jax.lax.Precision.HIGHEST)))
             resid = resid - (resid @ vc)[:, None] * vc[None, :]
     return (np.ascontiguousarray(np.stack(vs)),
             np.ascontiguousarray(np.stack(projs)))

@@ -44,7 +44,6 @@ launch (plus host sync) per segment.
 from __future__ import annotations
 
 import threading
-import traceback
 
 import numpy as np
 
@@ -217,21 +216,19 @@ class StreamingSNNIndex:
 
     def _prime(self, plan: _engine.SegmentPack,
                spec_from: _engine.SegmentPack | None = None) -> None:
-        """Warm ``plan`` pre-publish (mutator thread; failures non-fatal)."""
-        try:
-            if self._warmer is not None:
-                self._warmer(plan, spec_from)
-            else:
-                buckets = (self._warm_buckets()
-                           if callable(self._warm_buckets)
-                           else self._warm_buckets)
-                _engine.warm_plan(plan, m_pads=tuple(buckets) or (128,),
-                                  spec_from=spec_from, **self._warm_kwargs)
-        except Exception:
-            # warming is a pure performance action: a plan that failed to
-            # warm still answers every query correctly, just colder — never
-            # let it block the publish
-            traceback.print_exc()
+        """Warm ``plan`` pre-publish (mutator thread).
+
+        A failure propagates and the mutation does not publish: warming
+        runs the very kernels the next query would, so a plan that cannot
+        warm cannot answer either.
+        """
+        if self._warmer is not None:
+            self._warmer(plan, spec_from)
+            return
+        buckets = (self._warm_buckets() if callable(self._warm_buckets)
+                   else self._warm_buckets)
+        _engine.warm_plan(plan, m_pads=tuple(buckets) or (128,),
+                          spec_from=spec_from, **self._warm_kwargs)
 
     def _next_plan(self, parts: tuple):
         """(segments, plan) for a snapshot about to publish.
@@ -379,99 +376,112 @@ class StreamingSNNIndex:
             if pts.shape[0] == 0:
                 return
             with self._lock:
+                raw_before = list(self._raw_parts)
                 if width_free and self._raw_parts[0].shape[1] != pts.shape[1]:
                     # the first real batch commits the width of an empty seed
                     self._raw_parts = [np.zeros((0, pts.shape[1]), np.float32)]
                 parts = list(self._state[0])
                 self._raw_parts.append(pts)
-            base = parts[0]
-            start_id = sum(p.n for p in parts)
-            if base.n == 0:
-                # an empty base has no meaningful mu/v1 to freeze; the first
-                # real batch IS the build
-                self._full_rebuild()
-                return
-            if self.metric == "mips":
-                if float(np.einsum("ij,ij->i", pts, pts).max()) > base.xi**2:
-                    # the frozen lift cannot represent a larger-norm point
-                    self._full_rebuild()
-                    return
-            t, _ = _metrics.transform_data(pts, self.metric, xi=base.xi)
-            x = (t - base.mu[None, :]).astype(base.xs.dtype)
-            al = x @ base.v1
-            loc = np.argsort(al, kind="stable")
-            xs = np.ascontiguousarray(x[loc])
-            als = np.ascontiguousarray(al[loc])
-            # project onto the base's FROZEN extra components too: the box
-            # bound (like the window) is valid for any fixed ||v|| <= 1
-            # direction, so deltas inherit the base's basis unchanged and
-            # packed queries keep pruning across base + deltas uniformly
-            base_vs = np.asarray(base.vs)
-            projs = np.concatenate(
-                [als[None, :],
-                 (xs @ base_vs[1:].T).T.astype(np.float32)]) \
-                if base_vs.shape[0] > 1 else als[None, :]
-            delta = _snn.SNNIndex(
-                base.mu, base.v1, xs, als,
-                0.5 * np.einsum("ij,ij->i", xs, xs),
-                (start_id + loc).astype(np.int64),
-                self.metric, base.xi,
-                vs=base_vs, projs=projs)
-            parts.append(delta)
-            n_total = start_id + delta.n
-            if n_total >= self.rebuild_ratio * max(self._n_at_build, 1):
-                self._full_rebuild()
-                return
-            n_delta = sum(p.n for p in parts[1:])
-            if (len(parts) - 1 > self.max_deltas
-                    or n_delta > self.delta_ratio * max(base.n, 1)):
-                merged = parts[0]
-                for p in parts[1:]:
-                    merged = merge_sorted_indexes(merged, p)
-                segs, plan = self._next_plan((merged,))
+            try:
+                self._absorb(pts, parts)
+            except BaseException:
+                # nothing was published: drop the rows again so `raw` keeps
+                # matching the served parts
                 with self._lock:
-                    self._generation += 1
-                    self._state = ((merged,), segs, plan)
+                    self._raw_parts = raw_before
+                raise
+
+    def _absorb(self, pts: np.ndarray, parts: list) -> None:
+        """`append`'s work after the raw rows are recorded (caller holds
+        ``_mutate``): delta build, merge or re-index, then the publish."""
+        base = parts[0]
+        start_id = sum(p.n for p in parts)
+        if base.n == 0:
+            # an empty base has no meaningful mu/v1 to freeze; the first
+            # real batch IS the build
+            self._full_rebuild()
+            return
+        if self.metric == "mips":
+            if float(np.einsum("ij,ij->i", pts, pts).max()) > base.xi**2:
+                # the frozen lift cannot represent a larger-norm point
+                self._full_rebuild()
+                return
+        t, _ = _metrics.transform_data(pts, self.metric, xi=base.xi)
+        x = (t - base.mu[None, :]).astype(base.xs.dtype)
+        al = x @ base.v1
+        loc = np.argsort(al, kind="stable")
+        xs = np.ascontiguousarray(x[loc])
+        als = np.ascontiguousarray(al[loc])
+        # project onto the base's FROZEN extra components too: the box
+        # bound (like the window) is valid for any fixed ||v|| <= 1
+        # direction, so deltas inherit the base's basis unchanged and
+        # packed queries keep pruning across base + deltas uniformly
+        base_vs = np.asarray(base.vs)
+        projs = np.concatenate(
+            [als[None, :],
+             (xs @ base_vs[1:].T).T.astype(np.float32)]) \
+            if base_vs.shape[0] > 1 else als[None, :]
+        delta = _snn.SNNIndex(
+            base.mu, base.v1, xs, als,
+            0.5 * np.einsum("ij,ij->i", xs, xs),
+            (start_id + loc).astype(np.int64),
+            self.metric, base.xi,
+            vs=base_vs, projs=projs)
+        parts.append(delta)
+        n_total = start_id + delta.n
+        if n_total >= self.rebuild_ratio * max(self._n_at_build, 1):
+            self._full_rebuild()
+            return
+        n_delta = sum(p.n for p in parts[1:])
+        if (len(parts) - 1 > self.max_deltas
+                or n_delta > self.delta_ratio * max(base.n, 1)):
+            merged = parts[0]
+            for p in parts[1:]:
+                merged = merge_sorted_indexes(merged, p)
+            segs, plan = self._next_plan((merged,))
+            with self._lock:
+                self._generation += 1
+                self._state = ((merged,), segs, plan)
+        else:
+            # incremental plan epoch: pad-stack the delta's segment now
+            # (outside the state lock) and extend the cached plan with
+            # one slab concatenation — queries on the new snapshot reuse
+            # the base's device-resident stack instead of rebuilding it
+            seg_delta = _engine.segment_from_index(delta,
+                                                  block=self.block)
+            # read as late as possible: a plan a racing query built
+            # during the heavy batch work above is seen here and
+            # extended rather than dropped.  (If the read is None, the
+            # publish follows within microseconds — a query completing
+            # a build inside that window loses only its cache
+            # write-back, never correctness.)
+            with self._lock:
+                prev_plan = self._state[2]
+            if prev_plan is not None:
+                new_plan = prev_plan.extend([seg_delta])
+            elif self._warm:
+                # nothing live to extend — build the next epoch whole so
+                # the publish still carries a warm plan (first append
+                # after a drop_plan/eviction, or a never-queried index)
+                segs_now = tuple(
+                    s if s is not None
+                    else _engine.segment_from_index(p, block=self.block)
+                    for p, s in zip(parts[:-1], self._state[1]))
+                new_plan = _engine.SegmentPack.build(
+                    [*segs_now, seg_delta], epoch=self._generation + 1)
             else:
-                # incremental plan epoch: pad-stack the delta's segment now
-                # (outside the state lock) and extend the cached plan with
-                # one slab concatenation — queries on the new snapshot reuse
-                # the base's device-resident stack instead of rebuilding it
-                seg_delta = _engine.segment_from_index(delta,
-                                                      block=self.block)
-                # read as late as possible: a plan a racing query built
-                # during the heavy batch work above is seen here and
-                # extended rather than dropped.  (If the read is None, the
-                # publish follows within microseconds — a query completing
-                # a build inside that window loses only its cache
-                # write-back, never correctness.)
-                with self._lock:
-                    prev_plan = self._state[2]
-                if prev_plan is not None:
-                    new_plan = prev_plan.extend([seg_delta])
-                elif self._warm:
-                    # nothing live to extend — build the next epoch whole so
-                    # the publish still carries a warm plan (first append
-                    # after a drop_plan/eviction, or a never-queried index)
-                    segs_now = tuple(
-                        s if s is not None
-                        else _engine.segment_from_index(p, block=self.block)
-                        for p, s in zip(parts[:-1], self._state[1]))
-                    new_plan = _engine.SegmentPack.build(
-                        [*segs_now, seg_delta], epoch=self._generation + 1)
-                else:
-                    new_plan = None
-                if self._warm and new_plan is not None:
-                    # double-buffered epoch: compile/adopt-spec on THIS
-                    # (mutator) thread before anyone can observe the plan
-                    self._prime(new_plan, spec_from=prev_plan)
-                with self._lock:
-                    # re-read the segment cache at publish time: _mutate
-                    # guarantees parts didn't change, but a query may have
-                    # filled segments since we started — keep its work
-                    self._generation += 1
-                    self._state = (tuple(parts),
-                                   (*self._state[1], seg_delta), new_plan)
+                new_plan = None
+            if self._warm and new_plan is not None:
+                # double-buffered epoch: compile/adopt-spec on THIS
+                # (mutator) thread before anyone can observe the plan
+                self._prime(new_plan, spec_from=prev_plan)
+            with self._lock:
+                # re-read the segment cache at publish time: _mutate
+                # guarantees parts didn't change, but a query may have
+                # filled segments since we started — keep its work
+                self._generation += 1
+                self._state = (tuple(parts),
+                               (*self._state[1], seg_delta), new_plan)
 
     def _full_rebuild(self) -> None:
         """Build a fresh base (caller holds ``_mutate``) and publish it."""

@@ -21,9 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _ring_body(x_local, w_local, axis: str):
-    # jax.lax.axis_size came and went across jax versions; psum of ones is
-    # the portable spelling (constant-folded under shard_map)
-    n = int(jax.lax.psum(1, axis))
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
     rows = x_local.shape[-2] if x_local.ndim > 1 else x_local.shape[0]
@@ -40,11 +38,8 @@ def _ring_body(x_local, w_local, axis: str):
 
     acc = jnp.zeros((rows * n, w_local.shape[-1]), x_local.dtype)
     # mark the accumulator as device-varying over the ring axis (shard_map
-    # VMA typing: the carry must match the loop body's varying type); pvary
-    # only exists on jax versions that do that typing — elsewhere it's a no-op
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        acc = pvary(acc, (axis,))
+    # VMA typing: the carry must match the loop body's varying type)
+    acc = jax.lax.pcast(acc, (axis,), to="varying")
     chunk, acc = jax.lax.fori_loop(0, n, lambda i, c: step(i, c),
                                    (x_local, acc))
     return acc

@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _bag_kernel(ids_ref, table_row_ref, out_ref):
     i = pl.program_id(0)
@@ -57,7 +54,7 @@ def embedding_bag(ids: jax.Array, table: jax.Array, *, interpret: bool = True):
         _bag_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), table.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
     )(ids.reshape(-1), table)

@@ -44,11 +44,15 @@ Five entry kernels share the body:
     multi-segment index instead of one launch (plus host sync) per segment.
 
 Layout notes (TPU): 1-D per-row arrays (alpha, half-norm, per-query scalars)
-are carried as (1, n)/(1, m) so the last dim is the 128-lane axis; ``d`` is
+are carried as (1, n)/(1, m) so the last dim is the 128-lane axis; stacked
+per-row arrays ride as (S, 1, n) with (1, 1, bn) blocks, because a block's
+last two dims must be (8, 128)-divisible or span the array.  ``d`` is
 zero-padded to a multiple of 128 for the MXU (zero features change nothing).
 ``pq`` rides as (ke, tq) tiles and ``px`` as (ke, bn) — ke is tiny (default
 2 extra components), so the box adds O(ke) VPU compares per candidate against
-the O(d) MXU work it saves.
+the O(d) MXU work it saves.  The f32 distance contraction runs at HIGHEST
+precision: a default-precision f32 matmul may take bf16 passes on the MXU,
+which would move the radius boundary.
 """
 from __future__ import annotations
 
@@ -61,15 +65,34 @@ import jax.experimental.pallas.tpu as pltpu
 
 from .ref import MIX_EPS, box_mask, norm_scales
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 BIG = float(jnp.finfo(jnp.float32).max / 8)
+
+# Pass-2 flat outputs are (tiles, 8, 128) arrays: one (8, 128) tile holds
+# _TILE consecutive CSR slots, and a row's run inside one db block (at most
+# bn <= _TILE survivors) always lies inside two consecutive tiles.
+_TILE_BITS = 10
+_TILE = 1 << _TILE_BITS  # 8 * 128
+
+# Largest flat capacity one compaction call takes: both flat outputs stay
+# resident in VMEM (8 bytes per slot).  2**23 slots (64 MiB) is the largest
+# power of two that compiles for a v5e core (128 MiB of VMEM); 2**24 is
+# refused for exceeding it.
+MAX_NNZ = 1 << 23
 
 
 def _window_hit(aq, r, a_lo, a_hi):
     """Does any query window [aq-r, aq+r] in the tile intersect [a_lo, a_hi]?"""
     return jnp.any((aq + r >= a_lo) & (aq - r <= a_hi))
+
+
+def _dot_f32(q, x):
+    """q @ x.T in full f32 (HIGHEST: no bf16 passes on the MXU)."""
+    return jax.lax.dot_general(
+        q, x,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _tile_body(q, aq, r, th, x, al, hn, pq=None, px=None):
@@ -83,11 +106,7 @@ def _tile_body(q, aq, r, th, x, al, hn, pq=None, px=None):
     superset of the distance predicate, so ``dhalf`` at kept positions is
     unchanged by it.
     """
-    s = jax.lax.dot_general(
-        q, x,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (tq, bn)
+    s = _dot_f32(q, x)  # (tq, bn)
     dhalf = hn - s  # (1, bn) broadcast over (tq, bn)
     aqc = aq[0, :][:, None]          # (tq, 1)
     rc = r[0, :][:, None]
@@ -129,13 +148,9 @@ def _count_tile(q, aq, r, th, x, al, hn, pq, px, mix):
     cnt = jnp.sum(definite.astype(jnp.int32), axis=1)
 
     def verify(_):
-        s32 = jax.lax.dot_general(
-            q, x,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
         # the exact f32 predicate, same expression as `_tile_body`
-        return jnp.sum((band & ((hn - s32) <= thc)).astype(jnp.int32), axis=1)
+        return jnp.sum((band & ((hn - _dot_f32(q, x)) <= thc))
+                       .astype(jnp.int32), axis=1)
 
     return cnt + jax.lax.cond(jnp.any(band), verify,
                               lambda _: jnp.zeros_like(cnt), 0)
@@ -202,18 +217,18 @@ def _count_stacked_kernel(mix, q_ref, aq_ref, r_ref, th_ref, x_ref, al_ref,
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a_lo = al_ref[0, 0]
-    a_hi = al_ref[0, al_ref.shape[1] - 1]
+    a_lo = al_ref[0, 0, 0]
+    a_hi = al_ref[0, 0, al_ref.shape[2] - 1]
     hit = _window_hit(aq_ref[0, :], r_ref[0, :], a_lo, a_hi)
 
     @pl.when(hit)
     def _():
         cnt = _count_tile(
             q_ref[...], aq_ref[...], r_ref[...], th_ref[...], x_ref[0],
-            al_ref[...], hn_ref[...],
+            al_ref[0], hn_ref[0],
             None if pq_ref is None else pq_ref[...],
             None if px_ref is None else px_ref[0], mix)
-        out_ref[...] += cnt[None, :]
+        out_ref[0] += cnt[None, :]
 
 
 def _grid_specs(m, n, d, tq, bn, ke=0):
@@ -237,7 +252,7 @@ def _grid_specs(m, n, d, tq, bn, ke=0):
 
 def _compiler_params():
     # block dim 0 (query tiles) is parallel; dim 1 revisits the count output.
-    return _CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY))
 
 
@@ -302,16 +317,141 @@ def snn_count(q, aq, r, thresh, xs, alphas, half_norms, pq=None, px=None, *,
 # --------------------------------------------------------------------------- #
 # Pass-2 CSR compaction                                                        #
 # --------------------------------------------------------------------------- #
+def _exclusive_prefix(keep):
+    """(tq, bn) int32: survivors before each column within its row.
+
+    A matmul with a strictly-upper triangle of ones: 0/1 operands are exact
+    in bf16 and the f32 accumulation counts exactly (bn < 2^24).
+    """
+    bn = keep.shape[1]
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+           < jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1))
+    return jax.lax.dot_general(
+        jnp.where(keep, 1.0, 0.0).astype(jnp.bfloat16),
+        jnp.where(tri, 1.0, 0.0).astype(jnp.bfloat16),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _place_run(dest, bits, t_off):
+    """One row's survivors moved to their slots in a two-tile window.
+
+    ``dest`` (1, bn) is each survivor's rank in its run (-1 for pruned
+    columns), ``bits`` (1, bn) its dhalf bit pattern, ``t_off`` the run's
+    first slot in the window.  Returns (local column, dhalf bits) as
+    (16, 128) int32 windows.  The move is a one-hot matmul over byte planes:
+    every operand is an integer < 256 (exact in bf16) and every output sums
+    exactly one nonzero product, so all 32 bits arrive unchanged.
+    """
+    bn = dest.shape[1]
+    slot = jnp.where(dest >= 0, dest + t_off, -1)
+    row_of = slot >> 7  # -1 stays -1: pruned columns match no row
+    lane_of = slot & 127
+    local = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+    planes = [local & 255, local >> 8] + [(bits >> s) & 255
+                                          for s in (0, 8, 16, 24)]
+    in_row = jax.lax.broadcasted_iota(jnp.int32, (16, bn), 0) == row_of
+    a = jnp.concatenate([jnp.where(in_row, p, 0) for p in planes], axis=0)
+    onehot = jax.lax.broadcasted_iota(jnp.int32, (128, bn), 0) == lane_of
+    w = jax.lax.dot_general(
+        a.astype(jnp.float32).astype(jnp.bfloat16),
+        jnp.where(onehot, 1.0, 0.0).astype(jnp.bfloat16),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)  # (96, 128)
+    local_w = w[0:16] | (w[16:32] << 8)
+    bits_w = (w[32:48] | (w[48:64] << 8) | (w[64:80] << 16)
+              | (w[80:96] << 24))
+    return local_w, bits_w
+
+
+def _scatter_cell(keep, dhalf, base, col0, idx_ref, dh_ref, dest_scr,
+                  bits_scr):
+    """Write one cell's survivors into the flat CSR outputs.
+
+    Survivor j of query row k goes to ``base[k]`` + (survivors before j in
+    this block) — ascending sorted order, so each CSR row is written left
+    to right exactly once across the block loop.  Rows without survivors
+    in this block cost one scalar test.  Returns the (1, tq) per-row counts.
+    """
+    tq, bn = keep.shape
+    dest = jnp.where(keep, _exclusive_prefix(keep), -1)
+    bits = jax.lax.bitcast_convert_type(dhalf, jnp.int32)
+    for g in range(tq // 8):  # rows addressable through the leading axis
+        dest_scr[g] = dest[g * 8:(g + 1) * 8]
+        bits_scr[g] = bits[g * 8:(g + 1) * 8]
+    cnt = jnp.sum(keep.astype(jnp.int32), axis=1)[None, :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tq), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, bn), 0)
+    slots = (jax.lax.broadcasted_iota(jnp.int32, (16, 128), 0) * 128
+             + jax.lax.broadcasted_iota(jnp.int32, (16, 128), 1))
+
+    def row(k, carry):
+        n_k = jnp.sum(jnp.where(lane == k, cnt, 0))
+
+        @pl.when(n_k > 0)
+        def _():
+            b_k = jnp.sum(jnp.where(lane == k, base, 0))
+            t0 = b_k >> _TILE_BITS
+            t_off = b_k & (_TILE - 1)
+            pick = sub == (k & 7)
+            d_k = jnp.sum(jnp.where(pick, dest_scr[k >> 3], 0), axis=0,
+                          keepdims=True)
+            bits_k = jnp.sum(jnp.where(pick, bits_scr[k >> 3], 0), axis=0,
+                             keepdims=True)
+            local_w, bits_w = _place_run(d_k, bits_k, t_off)
+            run = (slots >= t_off) & (slots < t_off + n_k)
+            win = pl.ds(t0, 2)
+            old_i = idx_ref[win].reshape(16, 128)
+            idx_ref[win] = jnp.where(run, local_w + col0,
+                                     old_i).reshape(2, 8, 128)
+            old_d = dh_ref[win].reshape(16, 128)
+            dh_ref[win] = jnp.where(
+                run, jax.lax.bitcast_convert_type(bits_w, jnp.float32),
+                old_d).reshape(2, 8, 128)
+
+        return carry
+
+    @pl.when(jnp.sum(cnt) > 0)
+    def _():
+        jax.lax.fori_loop(0, tq, row, 0)
+
+    return cnt
+
+
+def _compact_outputs(nnz: int, tq: int, bn: int):
+    """(out_shape, scratch_shapes, vmem limit) shared by both compactions.
+
+    The flat outputs are whole-array VMEM blocks of ``nnz`` slots rounded up
+    to (8, 128) tiles plus one spare tile, so a run's two-tile window never
+    leaves the array.
+    """
+    if bn > _TILE:
+        raise ValueError(f"bn={bn} exceeds the {_TILE}-slot output tile")
+    if nnz > MAX_NNZ:
+        raise ValueError(f"flat CSR capacity {nnz} exceeds MAX_NNZ="
+                         f"{MAX_NNZ}; split the query batch")
+    n_tiles = -(-nnz // _TILE) + 1
+    out_shape = [jax.ShapeDtypeStruct((n_tiles, 8, 128), jnp.int32),
+                 jax.ShapeDtypeStruct((n_tiles, 8, 128), jnp.float32)]
+    scratch = [pltpu.VMEM((1, tq), jnp.int32),             # write cursor
+               pltpu.VMEM((tq // 8, 8, bn), jnp.int32),    # run ranks
+               pltpu.VMEM((tq // 8, 8, bn), jnp.int32)]    # dhalf bits
+    # the two resident outputs plus room for the tile operands
+    vmem = 2 * n_tiles * _TILE * 4 + (32 << 20)
+    return out_shape, scratch, vmem
+
+
+def _flat(out_idx, out_dh, nnz: int):
+    return out_idx.reshape(-1)[:nnz], out_dh.reshape(-1)[:nnz]
+
+
 def _compact_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
                     x_ref, al_ref, hn_ref, *rest):
-    pq_ref, px_ref, (idx_ref, dh_ref, cursor_ref) = _split_rest(rest, 3)
+    pq_ref, px_ref, (idx_ref, dh_ref, cursor_ref, dest_scr, bits_scr) = \
+        _split_rest(rest, 5)
     qi = pl.program_id(0)
     bi = pl.program_id(1)
     bn = x_ref.shape[0]
-    # The last flat slot is a trash slot: every (row, col) pair gets exactly one
-    # unconditional store, pruned pairs land there, so no divergent control flow
-    # is needed in the scatter loop.
-    trash = idx_ref.shape[1] - 1
 
     @pl.when((qi == 0) & (bi == 0))
     def _():
@@ -333,40 +473,9 @@ def _compact_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
             al_ref[...], hn_ref[...],
             None if pq_ref is None else pq_ref[...],
             None if px_ref is None else px_ref[...])
-        keep_i = keep.astype(jnp.int32)
-        # Survivor j of query row k goes to offsets[k] + cursor[k] + (number of
-        # survivors before j in this block) — ascending sorted order, so each
-        # CSR row is written left-to-right exactly once across the block loop.
-        within = jnp.cumsum(keep_i, axis=1) - 1
-        base = off_ref[0, :] + cursor_ref[0, :]
-        col0 = bi * bn
-
-        def row_body(k, _):
-            pos = jnp.where(keep[k], base[k] + within[k], trash)
-
-            def scatter_row(_):
-                def el_body(j, __):
-                    idx_ref[0, pl.ds(pos[j], 1)] = (col0 + j)[None].astype(jnp.int32)
-                    dh_ref[0, pl.ds(pos[j], 1)] = dhalf[k, j][None]
-                    return 0
-
-                return jax.lax.fori_loop(0, bn, el_body, 0)
-
-            # rows whose window missed this block (common in a hit tile) skip
-            # their bn stores entirely; rows WITH survivors still pay bn
-            # serialized stores (pruned pairs hit the trash slot) — the cost
-            # bound is (rows with >=1 survivor) * bn, not survivor count
-            return jax.lax.cond(jnp.sum(keep_i[k]) > 0, scatter_row,
-                                lambda _: 0, 0)
-
-        jax.lax.fori_loop(0, keep.shape[0], row_body, 0)
-        cursor_ref[...] += jnp.sum(keep_i, axis=1)[None, :]
-
-    @pl.when((qi == pl.num_programs(0) - 1) & (bi == pl.num_programs(1) - 1))
-    def _():
-        # the trash slot absorbed every pruned pair; restore its sentinel
-        idx_ref[0, pl.ds(trash, 1)] = jnp.full((1,), -1, jnp.int32)
-        dh_ref[0, pl.ds(trash, 1)] = jnp.full((1,), BIG, jnp.float32)
+        cursor_ref[...] += _scatter_cell(
+            keep, dhalf, off_ref[...] + cursor_ref[...], bi * bn,
+            idx_ref, dh_ref, dest_scr, bits_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("nnz", "tq", "bn", "interpret"))
@@ -376,9 +485,9 @@ def snn_compact(q, aq, r, thresh, offsets, xs, alphas, half_norms,
     """Scatter surviving (sorted-row index, dhalf) pairs into flat CSR arrays.
 
     ``offsets[k]`` is the first flat slot of query k's CSR row (from the pass-1
-    count prefix sum); ``nnz`` is the flat capacity INCLUDING one trailing trash
-    slot (callers pass >= total_neighbors + 1; bucketing it, e.g. to the next
-    power of two, bounds recompilation).  Returns (idx (nnz,) int32 sorted-row
+    count prefix sum); ``nnz`` is the flat capacity INCLUDING one trailing
+    slot that stays -1 (callers pass >= total_neighbors + 1; bucketing it,
+    e.g. to the next power of two, bounds recompilation).  Returns (idx (nnz,) int32 sorted-row
     positions with -1 in unwritten slots, dhalf (nnz,) f32).  Same padding
     contract as filter/count; padding queries must carry offsets < nnz.
     ``pq``/``px`` must match pass 1's — both passes then evaluate the same
@@ -388,12 +497,11 @@ def snn_compact(q, aq, r, thresh, offsets, xs, alphas, half_norms,
     output block, and a VMEM cursor carries each query's running write position
     across db blocks.
 
-    Memory: the flat outputs live in one VMEM block, so a single call supports
-    nnz up to roughly VMEM capacity (~2M pairs at 8 bytes each) — far beyond
-    the dense path's (m, n) ceiling, but not unbounded; callers with larger
-    result sets should split the query batch (serving's dispatcher batches
-    naturally).  Lifting this via HBM-resident outputs + manual DMA is future
-    work.
+    Memory: the flat outputs live in one VMEM block (8 bytes per slot), so
+    one call holds at most `MAX_NNZ` slots and raises beyond it; callers
+    with larger result sets split the query batch (serving's dispatcher
+    batches naturally).  Lifting this via HBM-resident outputs + manual DMA
+    is future work.
     """
     m, d = q.shape
     n = xs.shape[0]
@@ -405,26 +513,28 @@ def snn_compact(q, aq, r, thresh, offsets, xs, alphas, half_norms,
             alphas[None, :], half_norms[None, :])
     if ke:
         args += (pq, px)
+    out_shape, scratch, vmem = _compact_outputs(nnz, tq, bn)
+    whole = pl.BlockSpec(out_shape[0].shape, lambda qi, bi: (0, 0, 0))
     out_idx, out_dh = pl.pallas_call(
         _compact_kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, nnz), lambda qi, bi: (0, 0)),
-                   pl.BlockSpec((1, nnz), lambda qi, bi: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, nnz), jnp.int32),
-                   jax.ShapeDtypeStruct((1, nnz), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((1, tq), jnp.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY)),
+        out_specs=[whole, whole],
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
     )(*args)
-    return out_idx[0], out_dh[0]
+    return _flat(out_idx, out_dh, nnz)
 
 
 # --------------------------------------------------------------------------- #
 # Stacked-grid variants (one launch over a whole SegmentPack)                  #
 # --------------------------------------------------------------------------- #
 def _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke=0):
+    """Grid + input specs over a stack; per-row stacks arrive as (S, 1, n)."""
     grid = (n_seg, m // tq, n // bn)
     in_specs = [
         pl.BlockSpec((tq, d), lambda s, qi, bi: (qi, 0)),      # q
@@ -432,8 +542,8 @@ def _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke=0):
         pl.BlockSpec((1, tq), lambda s, qi, bi: (0, qi)),      # r
         pl.BlockSpec((1, tq), lambda s, qi, bi: (0, qi)),      # thresh
         pl.BlockSpec((1, bn, d), lambda s, qi, bi: (s, bi, 0)),  # xs stack
-        pl.BlockSpec((1, bn), lambda s, qi, bi: (s, bi)),      # alpha stack
-        pl.BlockSpec((1, bn), lambda s, qi, bi: (s, bi)),      # half-norm stack
+        pl.BlockSpec((1, 1, bn), lambda s, qi, bi: (s, 0, bi)),  # alpha stack
+        pl.BlockSpec((1, 1, bn), lambda s, qi, bi: (s, 0, bi)),  # half-norms
     ]
     if ke:
         in_specs += [
@@ -462,21 +572,22 @@ def snn_count_stacked(q, aq, r, thresh, xs, alphas, half_norms,
     n_seg, n, _ = xs.shape
     ke = 0 if pq is None else pq.shape[0]
     grid, in_specs = _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke)
-    args = (q, aq[None, :], r[None, :], thresh[None, :], xs, alphas,
-            half_norms)
+    args = (q, aq[None, :], r[None, :], thresh[None, :], xs,
+            alphas[:, None, :], half_norms[:, None, :])
     if ke:
         args += (pq, px)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_count_stacked_kernel, mixed),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tq), lambda s, qi, bi: (s, qi)),
-        out_shape=jax.ShapeDtypeStruct((n_seg, m), jnp.int32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((1, 1, tq), lambda s, qi, bi: (s, 0, qi)),
+        out_shape=jax.ShapeDtypeStruct((n_seg, 1, m), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
     )(*args)
+    return out[:, 0, :]
 
 
 def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
@@ -488,13 +599,13 @@ def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
     Offsets are per (segment, query) — the global CSR base plus the
     segment-axis exclusive prefix, both computed on device.
     """
-    pq_ref, px_ref, (idx_ref, dh_ref, cursor_ref) = _split_rest(rest, 3)
+    pq_ref, px_ref, (idx_ref, dh_ref, cursor_ref, dest_scr, bits_scr) = \
+        _split_rest(rest, 5)
     si = pl.program_id(0)
     qi = pl.program_id(1)
     bi = pl.program_id(2)
     bn = x_ref.shape[1]
     n_pad = pl.num_programs(2) * bn
-    trash = idx_ref.shape[1] - 1
 
     @pl.when((si == 0) & (qi == 0) & (bi == 0))
     def _():
@@ -505,44 +616,20 @@ def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
     def _():
         cursor_ref[...] = jnp.zeros_like(cursor_ref)
 
-    a_lo = al_ref[0, 0]
-    a_hi = al_ref[0, al_ref.shape[1] - 1]
+    a_lo = al_ref[0, 0, 0]
+    a_hi = al_ref[0, 0, al_ref.shape[2] - 1]
     hit = _window_hit(aq_ref[0, :], r_ref[0, :], a_lo, a_hi)
 
     @pl.when(hit)
     def _():
         keep, dhalf = _tile_body(
             q_ref[...], aq_ref[...], r_ref[...], th_ref[...], x_ref[0],
-            al_ref[...], hn_ref[...],
+            al_ref[0], hn_ref[0],
             None if pq_ref is None else pq_ref[...],
             None if px_ref is None else px_ref[0])
-        keep_i = keep.astype(jnp.int32)
-        within = jnp.cumsum(keep_i, axis=1) - 1
-        base = off_ref[0, :] + cursor_ref[0, :]
-        col0 = si * n_pad + bi * bn
-
-        def row_body(k, _):
-            pos = jnp.where(keep[k], base[k] + within[k], trash)
-
-            def scatter_row(_):
-                def el_body(j, __):
-                    idx_ref[0, pl.ds(pos[j], 1)] = (col0 + j)[None].astype(jnp.int32)
-                    dh_ref[0, pl.ds(pos[j], 1)] = dhalf[k, j][None]
-                    return 0
-
-                return jax.lax.fori_loop(0, bn, el_body, 0)
-
-            return jax.lax.cond(jnp.sum(keep_i[k]) > 0, scatter_row,
-                                lambda _: 0, 0)
-
-        jax.lax.fori_loop(0, keep.shape[0], row_body, 0)
-        cursor_ref[...] += jnp.sum(keep_i, axis=1)[None, :]
-
-    @pl.when((si == pl.num_programs(0) - 1) & (qi == pl.num_programs(1) - 1)
-             & (bi == pl.num_programs(2) - 1))
-    def _():
-        idx_ref[0, pl.ds(trash, 1)] = jnp.full((1,), -1, jnp.int32)
-        dh_ref[0, pl.ds(trash, 1)] = jnp.full((1,), BIG, jnp.float32)
+        cursor_ref[...] += _scatter_cell(
+            keep, dhalf, off_ref[0] + cursor_ref[...], si * n_pad + bi * bn,
+            idx_ref, dh_ref, dest_scr, bits_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("nnz", "tq", "bn", "interpret"))
@@ -555,7 +642,8 @@ def snn_compact_stacked(q, aq, r, thresh, offsets, xs, alphas, half_norms,
     ``offsets`` is (S, m): flat slot of segment s's first survivor for query
     k (global CSR base + segment-axis exclusive prefix).  Returns flat
     (idx (nnz,) int32 PACK-FLAT positions ``s * n_pad + local_row``,
-    dhalf (nnz,) f32); same trash-slot/-1 conventions as `snn_compact`.
+    dhalf (nnz,) f32); same capacity and -1/+BIG conventions as
+    `snn_compact`.
     All three grid dims are sequential: every cell scatters into the same
     flat output block, with the VMEM cursor carrying each query's running
     write position across a segment's db blocks.
@@ -565,23 +653,25 @@ def snn_compact_stacked(q, aq, r, thresh, offsets, xs, alphas, half_norms,
     ke = 0 if pq is None else pq.shape[0]
     grid, in_specs = _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke)
     in_specs = in_specs[:4] \
-        + [pl.BlockSpec((1, tq), lambda s, qi, bi: (s, qi))] + in_specs[4:]
-    args = (q, aq[None, :], r[None, :], thresh[None, :], offsets, xs,
-            alphas, half_norms)
+        + [pl.BlockSpec((1, 1, tq), lambda s, qi, bi: (s, 0, qi))] \
+        + in_specs[4:]
+    args = (q, aq[None, :], r[None, :], thresh[None, :], offsets[:, None, :],
+            xs, alphas[:, None, :], half_norms[:, None, :])
     if ke:
         args += (pq, px)
+    out_shape, scratch, vmem = _compact_outputs(nnz, tq, bn)
+    whole = pl.BlockSpec(out_shape[0].shape, lambda s, qi, bi: (0, 0, 0))
     out_idx, out_dh = pl.pallas_call(
         _compact_stacked_kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, nnz), lambda s, qi, bi: (0, 0)),
-                   pl.BlockSpec((1, nnz), lambda s, qi, bi: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, nnz), jnp.int32),
-                   jax.ShapeDtypeStruct((1, nnz), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((1, tq), jnp.int32)],
-        compiler_params=_CompilerParams(
+        out_specs=[whole, whole],
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY,
-                                 pltpu.ARBITRARY)),
+                                 pltpu.ARBITRARY),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
     )(*args)
-    return out_idx[0], out_dh[0]
+    return _flat(out_idx, out_dh, nnz)

@@ -117,7 +117,7 @@ def _count_stacked_kernel(mix, q_ref, aq_ref, r_ref, th_ref, x_ref, al_ref,
     pq_ref, px_ref, (out_ref,) = _split_rest(rest, 1)
     cnt = _count_tile_nobranch(
         q_ref[...], aq_ref[...], r_ref[...], th_ref[...], x_ref[0],
-        al_ref[...], hn_ref[...],
+        al_ref[0], hn_ref[0],
         None if pq_ref is None else pq_ref[...],
         None if px_ref is None else px_ref[0], mix)
     out_ref[...] = cnt[None, None, :]
@@ -183,8 +183,8 @@ def _partial_counts_stacked(q, aq, r, thresh, xs, alphas, half_norms, pq, px,
     n_seg, n, _ = xs.shape
     ke = 0 if pq is None else pq.shape[0]
     grid, in_specs = _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke)
-    args = (q, aq[None, :], r[None, :], thresh[None, :], xs, alphas,
-            half_norms)
+    args = (q, aq[None, :], r[None, :], thresh[None, :], xs,
+            alphas[:, None, :], half_norms[:, None, :])
     if ke:
         args += (pq, px)
     return pl.pallas_call(
@@ -259,7 +259,7 @@ def _scatter_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, base_ref,
     trash = idx_ref.shape[1] - 1
     keep, dhalf = _tile_body(
         q_ref[...], aq_ref[...], r_ref[...], th_ref[...], x_ref[0],
-        al_ref[...], hn_ref[...],
+        al_ref[0], hn_ref[0],
         None if pq_ref is None else pq_ref[...],
         None if px_ref is None else px_ref[0])
     keep_i = keep.astype(jnp.int32)
@@ -357,7 +357,7 @@ def snn_compact_stacked(q, aq, r, thresh, offsets, xs, alphas, half_norms,
     in_specs += [pl.BlockSpec((1, nnz), lambda s, qi, bi: (0, 0)),
                  pl.BlockSpec((1, nnz), lambda s, qi, bi: (0, 0))]
     args = (q, aq[None, :], r[None, :], thresh[None, :], bases, xs,
-            alphas, half_norms)
+            alphas[:, None, :], half_norms[:, None, :])
     if ke:
         args += (pq, px)
     n_in = len(args)

@@ -148,8 +148,6 @@ class Roofline:
 
 def analyze(compiled, model_flops: float, n_devices: int) -> Roofline:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict], newer a dict
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     raw_bytes = float(ca.get("bytes accessed", 0.0))
     hlo_text = compiled.as_text()

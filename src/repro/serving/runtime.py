@@ -21,12 +21,13 @@ This module is the mechanics under `serving.server.SNNServer` and
   request can starve behind later arrivals.  ``serve_policy == "window"``
   reproduces the legacy fixed ``serve_timeout_ms`` window.
 * `TenantRuntime` — one tenant's index + per-point reverse radii + the
-  batch executors (the fused CSR-family dispatch, the fixed-shape
-  fallback, the knn front-end).  `run_batch` guarantees EVERY request in
-  the batch gets a response: requests a degraded path cannot serve — and
-  requests lost to an executor exception — receive an error `Response`
-  immediately rather than leaving their callers blocked until the
-  `result()` timeout.
+  batch executors (the fused CSR-family dispatch, the fixed-shape path
+  selected by ``cfg.serve_exact=False``, the knn front-end).  `run_batch`
+  guarantees EVERY request in the batch gets a response: requests the
+  fixed-shape path cannot serve — and requests lost to an executor
+  exception, which is never retried on another path — receive an error
+  `Response` immediately rather than leaving their callers blocked until
+  the `result()` timeout.
 
 The executors are verbatim ports of the pre-split `SNNServer` bodies: the
 fused single-dispatch contract (a batch of mixed kinds/radii/k costs O(1)
@@ -301,61 +302,54 @@ class TenantRuntime:
         self._gen = self.index.generation
         self._stored = set()
         self._emit_fn = store
+        failures = []
         try:
             knn_sel = [i for i, r in enumerate(batch)
                        if r.kind == "snn-knn"]
             csr_sel = [i for i, r in enumerate(batch)
                        if r.kind != "snn-knn"]
-            if csr_sel:
-                self._serve_csr(batch, csr_sel)
-            if knn_sel:
+            for sel, serve in ((csr_sel, self._serve_csr),
+                               (knn_sel, self._respond_knn)):
+                if not sel:
+                    continue
                 try:
-                    self._respond_knn(batch, knn_sel)
-                except Exception:
+                    serve(batch, sel)
+                except Exception as e:
                     traceback.print_exc()
+                    failures.append(f"{type(e).__name__}: {e}")
         finally:
             # the no-silent-drop guarantee: whatever failed above, every
             # request's caller gets a fast error instead of a timeout
+            why = "; ".join(failures) or "see server log"
             for r in batch:
                 if r.id not in self._stored:
                     self._emit_error(r, f"{r.kind} request could not be "
-                                     f"served (executor failure; see "
-                                     f"server log)")
+                                     f"served (executor failure: {why})")
             if clock is not None:
                 clock.observe(time.monotonic() - t_svc)
             self._emit_fn = None
 
     def _serve_csr(self, batch, csr_sel) -> None:
-        cfg = self.cfg
-        if cfg.serve_exact:
-            try:
-                self._respond_csr_family(batch, csr_sel)
-                return
-            except Exception:
-                # The exact path's flat output is data-dependent (a
-                # pathologically dense batch can exceed the compact
-                # kernel's VMEM ceiling); degrade to the K-bounded
-                # fixed path — per-query radii there too.
-                traceback.print_exc()
+        """The CSR family on the exact path, or — only when
+        ``cfg.serve_exact`` is off — the K-bounded fixed path.  A failure
+        of the exact path is not retried on the fixed one: it propagates,
+        and `run_batch` answers the requests with errors."""
+        if self.cfg.serve_exact:
+            self._respond_csr_family(batch, csr_sel)
+            return
         # Only the plain-radius subset has a fixed-shape equivalent; answer
-        # join/count/reverse requests with an error NOW — the fallback used
-        # to drop them silently and their callers blocked the full
-        # result() timeout
+        # join/count/reverse requests with an error NOW instead of letting
+        # their callers block the full result() timeout
         fixed_sel = []
         for i in csr_sel:
             if batch[i].kind == "snn-radius":
                 fixed_sel.append(i)
-            elif batch[i].id not in self._stored:
+            else:
                 self._emit_error(
                     batch[i],
                     f"the fixed-shape path cannot serve {batch[i].kind} "
-                    f"requests"
-                    + (" (exact CSR path failed for this batch)"
-                       if cfg.serve_exact else " (cfg.serve_exact=False)"))
-        try:
-            self._respond_fixed(batch, fixed_sel)
-        except Exception:
-            traceback.print_exc()  # final sweep answers these with errors
+                    f"requests (cfg.serve_exact=False)")
+        self._respond_fixed(batch, fixed_sel)
 
     # ------------------------------------------------------------ emission
     def _emit(self, req: Request, *, indices, sq_dists, truncated=False,

@@ -67,6 +67,29 @@ def test_snn_kernel_block_pruning_no_false_negatives():
     assert (cnt == exact).all()
 
 
+@pytest.mark.parametrize("bn,t_off", [(128, 0), (512, 1023), (512, 77)])
+def test_place_run_moves_every_bit(bn, t_off):
+    """The pass-2 one-hot move reproduces each survivor's column and dhalf bit
+    pattern exactly (sign, exponent and low mantissa bytes included) at slot
+    ``t_off + rank`` of the two-tile window, and writes nothing else."""
+    from repro.kernels.snn_query import _place_run
+
+    rng = np.random.default_rng(bn + t_off)
+    keep = rng.random(bn) < 0.3
+    rank = np.where(keep, np.cumsum(keep) - 1, -1).astype(np.int32)
+    bits = rng.integers(-2**31, 2**31, bn, dtype=np.int64).astype(np.int32)
+    local_w, bits_w = _place_run(jnp.asarray(rank[None]),
+                                 jnp.asarray(bits[None]), t_off)
+    local_w = np.asarray(local_w).reshape(-1)
+    bits_w = np.asarray(bits_w).reshape(-1)
+    slots = t_off + rank[keep]
+    np.testing.assert_array_equal(local_w[slots], np.flatnonzero(keep))
+    np.testing.assert_array_equal(bits_w[slots], bits[keep])
+    rest = np.ones(local_w.size, bool)
+    rest[slots] = False
+    assert not local_w[rest].any() and not bits_w[rest].any()
+
+
 @pytest.mark.parametrize("v,d,b,f", [(50, 128, 16, 5), (10, 128, 3, 1),
                                      (200, 256, 32, 9), (64, 128, 64, 4)])
 def test_embedding_bag_kernel_matches_ref(v, d, b, f):

@@ -439,3 +439,38 @@ def test_executor_failure_sweep_answers_every_request(monkeypatch):
     server._run_batch(batch)
     assert server._results[0].error is not None
     assert server._results[1].error is not None
+
+
+def test_exact_path_failure_is_not_retried_on_fixed_path(monkeypatch):
+    """A failing exact batch answers with errors naming the failure; the
+    fixed top-K path never sees it."""
+    server, data, rng = _mk_server(n=400)
+    rt = server.runtime()
+    fixed_calls = []
+
+    def boom(*a, **k):
+        raise RuntimeError("kernel refused")
+
+    monkeypatch.setattr(rt, "_respond_csr_family", boom)
+    monkeypatch.setattr(rt, "_respond_fixed",
+                        lambda *a, **k: fixed_calls.append(a))
+    server._run_batch([_submit_like(Request(
+        query=rng.random(6).astype(np.float32), radius=0.4, id=0))])
+    assert fixed_calls == []
+    assert "kernel refused" in server._results[0].error
+
+
+def test_plan_warm_failure_blocks_the_publish():
+    """A plan that fails to warm is not published: append raises and the
+    index keeps its generation, its size and its raw rows."""
+    server, data, rng = _mk_server(n=400)
+    index = server.index
+
+    def boom(plan, spec_from):
+        raise RuntimeError("warm failed")
+
+    index.set_plan_warming(True, warmer=boom)
+    gen, n = index.generation, index.n
+    with pytest.raises(RuntimeError, match="warm failed"):
+        server.append(rng.random((5, 6)).astype(np.float32))
+    assert (index.generation, index.n, index.raw.shape[0]) == (gen, n, n)
