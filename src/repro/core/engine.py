@@ -64,6 +64,7 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..kernels import ops as _ops
 from ..kernels import ref as _ref
@@ -78,7 +79,8 @@ _REAL = _ops.BIG / 2
 # Dispatch instrumentation                                                     #
 # --------------------------------------------------------------------------- #
 _STAT_FIELDS = ("kernel_launches", "host_transfers", "jit_compiles",
-                "bytes_planned")
+                "bytes_planned", "grid_cells", "live_cells", "knn_rounds",
+                "knn_candidates", "knn_wanted")
 
 
 class _StatCounters:
@@ -107,8 +109,26 @@ class DispatchStats(threading.local):
     in this process, i.e. launches that forced an XLA compile
     (`kernels.registry.note_launch_signature`); ``bytes_planned`` counts
     bytes accounted by newly built static `MemoryPlan`s (one per
-    (pack epoch, query bucket)).  `benchmarks.common.dispatch_counts` reads
-    these to make packed-vs-looped overhead visible in the trajectory.
+    (pack epoch, query bucket)).  ``grid_cells`` counts the (live segment,
+    query tile, db block) cells of every stacked pass a device lane runs,
+    and ``live_cells`` those whose sorted window the kernel finds hit
+    (`_count_cells`): the rest skip the MXU but still fetch their blocks.
+    ``knn_rounds`` counts the kNN expansion loop's count passes,
+    ``knn_candidates`` the CSR entries of its final pass and
+    ``knn_wanted`` the neighbors asked for (sum of each query's k, capped
+    at n), so candidates per wanted neighbor is the final pass's excess
+    (`core.knn.query_knn`).
+
+    Readers: the on-chip benchmark's metrics (``bench/metrics``:
+    ``launches_per_row`` reads ``kernel_launches``, ``live_cells`` and
+    ``knn_candidates`` read the fields of their names, all over the
+    measured window's `aggregate`), `kernels.registry.compile_counts`
+    beside ``jit_compiles``, the serving registry's memory budget
+    (``bytes_planned``), `benchmarks.common.dispatch_counts` and
+    ``benchmarks/check_dispatch.py`` (launches and transfers per call), and
+    the tests.  Every ``kernel_launches`` increment opens one ``snn.launch``
+    profiler span and every ``host_transfers`` increment one ``snn.sync``
+    (`_Launch`, `_to_host`).
 
     Concurrency: the counters live in per-thread `_StatCounters` holders
     (``threading.local`` hands each thread its own on first touch), so the
@@ -162,6 +182,40 @@ del _f
 
 
 DISPATCH_STATS = DispatchStats()
+
+
+class _Launch(TraceAnnotation):
+    """One device dispatch: a ``kernel_launches`` count and an
+    ``snn.launch`` span named by ``op``.  A launch that met a new launch
+    signature (`kernels.registry.note_launch_signature`: an XLA compile)
+    carries ``new_signature=1``.  Like every span, it records only while
+    the profiler runs."""
+
+    def __init__(self, op: str):
+        super().__init__("snn.launch", op=op)
+        DISPATCH_STATS.kernel_launches += 1
+        self._compiles = DISPATCH_STATS.jit_compiles
+
+    def __exit__(self, *exc):
+        if DISPATCH_STATS.jit_compiles != self._compiles:
+            self.set_metadata(new_signature=1)
+        return super().__exit__(*exc)
+
+
+def _to_host(x):
+    """Wait for a device result and copy it to the host: one
+    ``host_transfers`` count and one ``snn.sync`` span.  A tuple comes back
+    in one `jax.device_get`."""
+    DISPATCH_STATS.host_transfers += 1
+    with TraceAnnotation("snn.sync"):
+        return jax.device_get(x) if isinstance(x, tuple) else np.asarray(x)
+
+
+def _fetch(fn, *args, **kwargs):
+    """Launch ``fn`` (a backend entry point) and wait for its result."""
+    with _Launch(fn.__name__):
+        out = fn(*args, **kwargs)
+    return _to_host(out)
 
 
 def _oracle() -> "_registry.Backend":
@@ -235,6 +289,9 @@ class Segment:
         values (float64) and the matching local positions — the packed
         oracle's interval-to-columns gather.
       xnorm_max: max real row norm (float64) — sizes the host-side box slack.
+      block_lo/block_hi: (n_pad / block,) host copies of the first and last
+        padded alpha of each row block, in the device arrays' dtype — the
+        block range each kernel cell tests (`_count_cells`).
     """
 
     xs: jnp.ndarray
@@ -250,6 +307,8 @@ class Segment:
     proj_sorted: np.ndarray | None = None
     proj_rank: np.ndarray | None = None
     xnorm_max: float = 0.0
+    block_lo: np.ndarray | None = None
+    block_hi: np.ndarray | None = None
 
     @property
     def n(self) -> int:
@@ -298,8 +357,13 @@ def make_segment(xs, alphas, half_norms, ids, *, block: int = 512,
             phi = np.full(pj_np.shape[0], -np.inf)
         ps = np.sort(pj_full.astype(np.float64), axis=1)
         pr = np.argsort(pj_full, axis=1, kind="stable").astype(np.int64)
+    # the padded alphas as the device holds them, read block by block
+    al_host = np.pad(alphas, (0, al_p.shape[0] - alphas.shape[0]),
+                     constant_values=_ops.BIG).astype(al_p.dtype)
+    blocks = al_host.reshape(-1, block)
     return Segment(xs_p, al_p, hn_p, np.asarray(ids, np.int64), lo, hi, block,
-                   pj, plo, phi, ps, pr, xnorm_max)
+                   pj, plo, phi, ps, pr, xnorm_max,
+                   blocks[:, 0].copy(), blocks[:, -1].copy())
 
 
 def _index_extra_projs(index) -> np.ndarray | None:
@@ -483,20 +547,18 @@ def run_csr(
             continue
         live.append(k)
         if backend.device:
-            DISPATCH_STATS.kernel_launches += 1
-            DISPATCH_STATS.host_transfers += 1
-            per[k] = np.asarray(backend.snn_count(
+            per[k] = _fetch(
+                backend.snn_count,
                 qp, aqp, rp, thp, seg.xs, seg.alphas, seg.half_norms,
                 pq_j, _px(seg), tq=query_tile, bn=seg.block,
-                mixed=mixed))[:m]
+                mixed=mixed)[:m]
         else:
             # Oracle fast path: one dense filter feeds BOTH passes (counts
             # and scatter); np.nonzero's row-major order IS the CSR order.
-            DISPATCH_STATS.kernel_launches += 1
-            DISPATCH_STATS.host_transfers += 1
-            dh = np.asarray(backend.snn_filter(
+            dh = _fetch(
+                backend.snn_filter,
                 qp, aqp, rp, thp, seg.xs, seg.alphas, seg.half_norms,
-                pq_j, _px(seg)))[:m]
+                pq_j, _px(seg))[:m]
             if cached_bytes + dh.nbytes <= budget:
                 cached[k] = dh
                 cached_bytes += dh.nbytes
@@ -523,23 +585,22 @@ def run_csr(
         if backend.device:
             off_k = jnp.asarray(np.concatenate(
                 [indptr[:-1] + seg_base[k], off_pad]).astype(np.int32))
-            DISPATCH_STATS.kernel_launches += 1
-            DISPATCH_STATS.host_transfers += 2
-            fi, fd = backend.snn_compact(
-                qp, aqp, rp, thp, off_k, seg.xs, seg.alphas, seg.half_norms,
-                pq_j, _px(seg), nnz=cap, tq=query_tile, bn=seg.block)
-            fi = np.asarray(fi)
+            with _Launch("snn_compact"):
+                fi, fd = backend.snn_compact(
+                    qp, aqp, rp, thp, off_k, seg.xs, seg.alphas,
+                    seg.half_norms, pq_j, _px(seg), nnz=cap, tq=query_tile,
+                    bn=seg.block)
+            fi = _to_host(fi)
             written = fi >= 0
             flat_ids[written] = seg.ids[fi[written]]
-            flat_dh[written] = np.asarray(fd)[written]
+            flat_dh[written] = _to_host(fd)[written]
         else:
             dh = cached[k]
             if dh is None:  # over-budget segment: identical jitted recompute
-                DISPATCH_STATS.kernel_launches += 1
-                DISPATCH_STATS.host_transfers += 1
-                dh = np.asarray(backend.snn_filter(
+                dh = _fetch(
+                    backend.snn_filter,
                     qp, aqp, rp, thp, seg.xs, seg.alphas, seg.half_norms,
-                    pq_j, _px(seg)))[:m]
+                    pq_j, _px(seg))[:m]
             keep = dh < _ops.BIG
             rows, cols = np.nonzero(keep)
             within = (np.cumsum(keep, axis=1) - 1)[rows, cols]
@@ -723,6 +784,8 @@ class SegmentPack:
     # starts fused instead of paying O(log nnz) ratchet misses again.
     _spec_hint: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    _bounds: tuple | None = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     @property
     def n_segments(self) -> int:
@@ -833,6 +896,22 @@ class SegmentPack:
                 ids[k, :s.n] = s.ids
             self._stacked = (xs, al, hn, ids)
         return self._stacked
+
+    def block_bounds(self) -> tuple[np.ndarray, np.ndarray]:
+        """(a_lo, a_hi): (S, n_pad / block) host arrays of the first and
+        last alpha of every row block of `stacked()`, +BIG past a segment's
+        own rows — the block ranges the stacked kernels' cells test.  Built
+        once per plan from the segments' host copies."""
+        if self._bounds is None:
+            nb = self.n_pad // self.block
+            dt = self.segments[0].block_lo.dtype
+            lo = np.full((self.n_segments, nb), _ops.BIG, dt)
+            hi = np.full((self.n_segments, nb), _ops.BIG, dt)
+            for k, s in enumerate(self.segments):
+                lo[k, :s.block_lo.size] = s.block_lo
+                hi[k, :s.block_hi.size] = s.block_hi
+            self._bounds = (lo, hi)
+        return self._bounds
 
     def concat(self):
         """(xs (N, d), alphas (N,), half_norms (N,), ids (N,) host int64,
@@ -969,22 +1048,77 @@ def pack_from_index(index, *, block: int = 512, epoch: int = 0) -> SegmentPack:
                              epoch=epoch)
 
 
-def _live_idx(pack: SegmentPack, aqp, rp, m: int, first_seg: int = 0,
-              pq64: np.ndarray | None = None,
-              qn64: np.ndarray | None = None) -> np.ndarray:
-    """The shared packed-executor prologue: which segments are live?
+def _prologue(pack: SegmentPack, qp, aqp, rp, thp, m: int, query_tile: int,
+              pq, first_seg: int = 0):
+    """The packed executors' shared host prologue, spanned as ``snn.prep``:
+    the batch bucket's memory plan, the effective box components and which
+    segments are live.  Returns (kq, pq_np, pq64, qn64, live_idx).
 
-    `run_csr_packed` and `run_counts_packed` MUST agree on this decision
-    (and on the gathers below) — the kNN front-end validates radii against
-    standalone counts and relies on the final count→compact execution
-    seeing the identical predicate inputs.
+    `run_csr_packed` and `run_counts_packed` MUST agree on the live set
+    (and on the gathers after it) — the kNN front-end validates radii
+    against standalone counts and relies on the final count→compact
+    execution seeing the identical predicate inputs.
     """
-    aq64 = np.asarray(aqp, np.float64)[:m]
-    r64 = np.asarray(rp, np.float64)[:m]
-    mask = pack.live_mask(aq64, r64, pq64, qn64)
-    if first_seg:
-        mask[:first_seg] = False
-    return np.nonzero(mask)[0]
+    with TraceAnnotation("snn.prep"):
+        if pack.segments:
+            pack.memory_plan(int(qp.shape[0]), query_tile)
+        kq = 0
+        if pq is not None and pack.ke:
+            kq = min(pack.ke, int(np.asarray(pq).shape[0]))
+        pq_np = pq64 = qn64 = None
+        if kq:
+            pq_np = np.asarray(pq, np.float32)[:kq]
+            pq64 = pq_np[:, :m].astype(np.float64)
+            qn64 = _qnorm64(rp, thp, m)
+        aq64 = np.asarray(aqp, np.float64)[:m]
+        r64 = np.asarray(rp, np.float64)[:m]
+        mask = pack.live_mask(aq64, r64, pq64, qn64)
+        if first_seg:
+            mask[:first_seg] = False
+    return kq, pq_np, pq64, qn64, np.nonzero(mask)[0]
+
+
+def _count_cells(pack: SegmentPack, live_idx: np.ndarray, aqp, rp, m: int,
+                 query_tile: int) -> tuple[int, int]:
+    """(grid cells, live cells) of ONE stacked pass over ``live_idx``.
+
+    The grid is (live segments x query tiles x db blocks).  A cell is live
+    when the kernel's `_window_hit` holds: some row of the tile has
+    ``aq + r >= a_lo`` and ``aq - r <= a_hi`` for the block's first and
+    last alpha, in the device arrays' float32.  Alphas ascend within a
+    segment, so both bounds ascend with the block and each row hits the
+    contiguous run of blocks ``[searchsorted(a_hi, aq - r),
+    searchsorted(a_lo, aq + r, "right"))``; a tile's live blocks are the
+    union of its rows' runs.  Padding rows (r = -BIG) hit no block, so
+    only the ``m`` real rows are read.  O(m log blocks) per segment.
+    """
+    lo_b, hi_b = pack.block_bounds()
+    aq, r = np.asarray(aqp)[:m], np.asarray(rp)[:m]
+    lo_q, hi_q = aq - r, aq + r
+    nb = lo_b.shape[1]
+    live = 0
+    for k in live_idx:
+        first = np.searchsorted(hi_b[k], lo_q, side="left")
+        last = np.searchsorted(lo_b[k], hi_q, side="right")
+        rows = np.flatnonzero(first < last)
+        if not rows.size:
+            continue
+        # tile t's runs shifted by t * nb stay apart from other tiles', so
+        # the union of all shifted runs sums the tiles' unions
+        off = (rows // query_tile) * nb
+        s, e = first[rows] + off, last[rows] + off
+        order = np.argsort(s)
+        s, e = s[order], e[order]
+        reach = np.maximum.accumulate(e)
+        live += int(e[0] - s[0]
+                    + np.maximum(e[1:] - np.maximum(s[1:], reach[:-1]),
+                                 0).sum())
+    return int(live_idx.size) * (int(aqp.shape[0]) // query_tile) * nb, live
+
+
+def _note_cells(cells: tuple[int, int], passes: int) -> None:
+    DISPATCH_STATS.grid_cells += passes * cells[0]
+    DISPATCH_STATS.live_cells += passes * cells[1]
 
 
 def _gather_live_concat(pack: SegmentPack, live_idx: np.ndarray,
@@ -1179,16 +1313,12 @@ def _run_csr_packed_pruned(pack, qp, aqp, rp, thp, m, live_idx, *,
         sub = (jnp.asarray(xs_s[cand_p]), jnp.asarray(al_s[cand_p]),
                jnp.asarray(hn_s[cand_p]))
         pq_t, px_t = pq_j[:, t0:t1], jnp.asarray(px_s[:, cand_p])
-        DISPATCH_STATS.kernel_launches += 1
-        DISPATCH_STATS.host_transfers += 1
-        dh_t = np.asarray(_oracle().snn_filter(q_t, aq_t, r_t, th_t, *sub,
-                                               pq_t, px_t))[:tm]
+        dh_t = _fetch(_oracle().snn_filter, q_t, aq_t, r_t, th_t, *sub,
+                      pq_t, px_t)[:tm]
         keep_t = dh_t < _ops.BIG
         if mixed:
-            DISPATCH_STATS.kernel_launches += 1
-            DISPATCH_STATS.host_transfers += 1
-            cnt_t = np.asarray(_oracle().snn_count(
-                q_t, aq_t, r_t, th_t, *sub, pq_t, px_t, mixed=True))[:tm]
+            cnt_t = _fetch(_oracle().snn_count, q_t, aq_t, r_t, th_t, *sub,
+                           pq_t, px_t, mixed=True)[:tm]
         else:
             cnt_t = keep_t.sum(axis=1)
         counts_pad[t0:t0 + tm] = cnt_t
@@ -1249,14 +1379,13 @@ def _run_counts_packed_pruned(pack, qp, aqp, rp, thp, m, live_idx, *,
         cand_p = np.full(cap_c, sent, np.int64)
         cand_p[:cand.size] = cand
         t1 = t0 + ptile
-        DISPATCH_STATS.kernel_launches += 1
-        DISPATCH_STATS.host_transfers += 1
-        counts[t0:t0 + tm] = np.asarray(_oracle().snn_count(
+        counts[t0:t0 + tm] = _fetch(
+            _oracle().snn_count,
             qp[t0:t1], aqp[t0:t1], rp[t0:t1], thp[t0:t1],
             jnp.asarray(xs_s[cand_p]), jnp.asarray(al_s[cand_p]),
             jnp.asarray(hn_s[cand_p]),
             pq_j[:, t0:t1], jnp.asarray(px_s[:, cand_p]),
-            mixed=mixed))[:tm]
+            mixed=mixed)[:tm]
     return counts
 
 
@@ -1338,16 +1467,12 @@ def _run_csr_packed_compacted(pack, qp, aqp, rp, thp, m, live_idx, *,
     alt = jnp.asarray(al_s[cand_p])
     hnt = jnp.asarray(hn_s[cand_p])
     pxt = jnp.asarray(px_s[:, cand_p])
-    DISPATCH_STATS.kernel_launches += 1
-    DISPATCH_STATS.host_transfers += 1
-    dh_t = np.asarray(_oracle().snn_filter_tiles(qt, aqt, rt, tht,
-                                                 xt, alt, hnt, pqt, pxt))
+    dh_t = _fetch(_oracle().snn_filter_tiles, qt, aqt, rt, tht,
+                  xt, alt, hnt, pqt, pxt)
     keep_t = dh_t < _ops.BIG
     if mixed:
-        DISPATCH_STATS.kernel_launches += 1
-        DISPATCH_STATS.host_transfers += 1
-        cnt_t = np.asarray(_oracle().snn_count_tiles(
-            qt, aqt, rt, tht, xt, alt, hnt, pqt, pxt, mixed=True))
+        cnt_t = _fetch(_oracle().snn_count_tiles,
+                       qt, aqt, rt, tht, xt, alt, hnt, pqt, pxt, mixed=True)
     else:
         cnt_t = keep_t.sum(axis=2)
     counts[:] = cnt_t.reshape(T * ptile)[:m]
@@ -1403,10 +1528,8 @@ def _run_counts_packed_compacted(pack, qp, aqp, rp, thp, m, live_idx, *,
     alt = jnp.asarray(al_s[cand_p])
     hnt = jnp.asarray(hn_s[cand_p])
     pxt = jnp.asarray(px_s[:, cand_p])
-    DISPATCH_STATS.kernel_launches += 1
-    DISPATCH_STATS.host_transfers += 1
-    cnt_t = np.asarray(_oracle().snn_count_tiles(
-        qt, aqt, rt, tht, xt, alt, hnt, pqt, pxt, mixed=mixed))
+    cnt_t = _fetch(_oracle().snn_count_tiles,
+                   qt, aqt, rt, tht, xt, alt, hnt, pqt, pxt, mixed=mixed)
     return cnt_t.reshape(T * ptile)[:m].astype(np.int64)
 
 
@@ -1462,17 +1585,8 @@ def run_csr_packed(
     ``use_pallas`` is a backend selector (`kernels.registry.resolve`).
     """
     backend = _registry.resolve(use_pallas)
-    if pack.segments:
-        pack.memory_plan(int(qp.shape[0]), query_tile)
-    kq = 0
-    if pq is not None and pack.ke:
-        kq = min(pack.ke, int(np.asarray(pq).shape[0]))
-    pq_np = pq64 = qn64 = None
-    if kq:
-        pq_np = np.asarray(pq, np.float32)[:kq]
-        pq64 = pq_np[:, :m].astype(np.float64)
-        qn64 = _qnorm64(rp, thp, m)
-    live_idx = _live_idx(pack, aqp, rp, m, first_seg, pq64, qn64)
+    kq, pq_np, pq64, qn64, live_idx = _prologue(
+        pack, qp, aqp, rp, thp, m, query_tile, pq, first_seg)
     indptr0 = np.zeros(m + 1, np.int64)
     if live_idx.size == 0:
         return (indptr0, np.zeros(m, np.int64), np.zeros(0, np.int64),
@@ -1516,10 +1630,8 @@ def run_csr_packed(
     # ---- pass 1: ONE filter launch over the ragged concatenation ---------
     # evaluated once and reused for the compaction — counts and scatter
     # cannot disagree
-    DISPATCH_STATS.kernel_launches += 1
-    DISPATCH_STATS.host_transfers += 1
-    dh_np = np.asarray(backend.snn_filter(
-        qp, aqp, rp, thp, xs_c, al_c, hn_c))  # zero-copy on CPU
+    dh_np = _fetch(backend.snn_filter,
+                   qp, aqp, rp, thp, xs_c, al_c, hn_c)  # zero-copy on CPU
     keep = dh_np < _ops.BIG
 
     # ---- prefix sums (vectorized; host == device memory on CPU) ----------
@@ -1583,36 +1695,29 @@ def run_counts_packed(
     selector (`kernels.registry.resolve`).
     """
     backend = _registry.resolve(use_pallas)
-    if pack.segments:
-        pack.memory_plan(int(qp.shape[0]), query_tile)
-    kq = 0
-    if pq is not None and pack.ke:
-        kq = min(pack.ke, int(np.asarray(pq).shape[0]))
-    pq_np = pq64 = qn64 = None
-    if kq:
-        pq_np = np.asarray(pq, np.float32)[:kq]
-        pq64 = pq_np[:, :m].astype(np.float64)
-        qn64 = _qnorm64(rp, thp, m)
-    live_idx = _live_idx(pack, aqp, rp, m, 0, pq64, qn64)
+    kq, pq_np, pq64, qn64, live_idx = _prologue(
+        pack, qp, aqp, rp, thp, m, query_tile, pq)
     if live_idx.size == 0:
         return np.zeros(m, np.int64)
 
     if backend.device:
-        xs, al, hn, _, px = _gather_live_stacked(pack, live_idx,
-                                                 with_px=True)
-        pq_j = None
-        if kq:
-            pq_j = jnp.asarray(pq_np)
-            if px.shape[1] != kq:
-                px = px[:, :kq]
-        else:
-            px = None
-        DISPATCH_STATS.kernel_launches += 1
-        per = backend.snn_count_stacked(qp, aqp, rp, thp, xs, al, hn,
-                                        pq_j, px, tq=query_tile,
-                                        bn=pack.block, mixed=mixed)
-        DISPATCH_STATS.host_transfers += 1
-        return np.asarray(per).sum(axis=0)[:m].astype(np.int64)
+        with TraceAnnotation("snn.prep"):
+            xs, al, hn, _, px = _gather_live_stacked(pack, live_idx,
+                                                     with_px=True)
+            pq_j = None
+            if kq:
+                pq_j = jnp.asarray(pq_np)
+                if px.shape[1] != kq:
+                    px = px[:, :kq]
+            else:
+                px = None
+            _note_cells(_count_cells(pack, live_idx, aqp, rp, m, query_tile),
+                        1)
+        with _Launch("snn_count_stacked"):
+            per = backend.snn_count_stacked(qp, aqp, rp, thp, xs, al, hn,
+                                            pq_j, px, tq=query_tile,
+                                            bn=pack.block, mixed=mixed)
+        return _to_host(per).sum(axis=0)[:m].astype(np.int64)
 
     if kq:
         if compacted is None or compacted:
@@ -1631,19 +1736,15 @@ def run_counts_packed(
         counts = np.zeros(m, np.int64)
         for k in live_idx:
             seg = pack.segments[k]
-            DISPATCH_STATS.kernel_launches += 1
-            DISPATCH_STATS.host_transfers += 1
-            counts += np.asarray(backend.snn_count(
+            counts += _fetch(
+                backend.snn_count,
                 qp, aqp, rp, thp, seg.xs, seg.alphas, seg.half_norms,
-                tq=query_tile, bn=seg.block, mixed=mixed))[:m]
+                tq=query_tile, bn=seg.block, mixed=mixed)[:m]
         return counts
-    DISPATCH_STATS.kernel_launches += 1
-    DISPATCH_STATS.host_transfers += 1
     if mixed:
-        return np.asarray(backend.snn_count(
-            qp, aqp, rp, thp, xs_c, al_c, hn_c,
-            mixed=True))[:m].astype(np.int64)
-    dh = np.asarray(backend.snn_filter(qp, aqp, rp, thp, xs_c, al_c, hn_c))[:m]
+        return _fetch(backend.snn_count, qp, aqp, rp, thp, xs_c, al_c, hn_c,
+                      mixed=True)[:m].astype(np.int64)
+    dh = _fetch(backend.snn_filter, qp, aqp, rp, thp, xs_c, al_c, hn_c)[:m]
     return (dh < _ops.BIG).sum(axis=1).astype(np.int64)
 
 
@@ -1670,77 +1771,85 @@ def _execute_stacked(pack: SegmentPack, qp, aqp, rp, thp, m: int,
     bucketed, so it converges after O(log nnz) misses)."""
     if backend is None:
         backend = _registry.get_backend("pallas-tpu")
-    xs, al, hn, ids, px = _gather_live_stacked(pack, live_idx, with_px=True)
-    kq = 0 if pq is None else int(pq.shape[0])
-    if kq:
-        if px.shape[1] != kq:
-            px = px[:, :kq]
-    else:
-        px = None
+    with TraceAnnotation("snn.prep"):
+        xs, al, hn, ids, px = _gather_live_stacked(pack, live_idx,
+                                                   with_px=True)
+        kq = 0 if pq is None else int(pq.shape[0])
+        if kq:
+            if px.shape[1] != kq:
+                px = px[:, :kq]
+        else:
+            px = None
+        spec = pack._spec.setdefault(
+            (int(qp.shape[0]), int(query_tile), live_idx.tobytes(), kq), {})
+        nnz_spec = spec.get("nnz_cap", 0)
+        if not nnz_spec:
+            # a fresh live-set key opens at the predecessor plan's
+            # ratcheted capacity (adopt_spec) instead of falling back to
+            # the classic path
+            nnz_spec = pack._spec_hint.get(
+                (int(qp.shape[0]), int(query_tile), kq), 0)
+        cells = _count_cells(pack, live_idx, aqp, rp, m, query_tile)
 
     # ---- speculative fused single-dispatch fast path ---------------------
-    spec = pack._spec.setdefault(
-        (int(qp.shape[0]), int(query_tile), live_idx.tobytes(), kq), {})
-    nnz_spec = spec.get("nnz_cap", 0)
-    if not nnz_spec:
-        # a fresh live-set key opens at the predecessor plan's ratcheted
-        # capacity (adopt_spec) instead of falling back to the classic path
-        nnz_spec = pack._spec_hint.get(
-            (int(qp.shape[0]), int(query_tile), kq), 0)
     if fused and nnz_spec:
-        DISPATCH_STATS.kernel_launches += 1
-        out = backend.snn_csr_fused_stacked(
-            qp, aqp, rp, thp, xs, al, hn, pq, px,
-            nnz_cap=nnz_spec, tq=query_tile, bn=pack.block, mixed=mixed)
+        with _Launch("snn_csr_fused_stacked"):
+            out = backend.snn_csr_fused_stacked(
+                qp, aqp, rp, thp, xs, al, hn, pq, px,
+                nnz_cap=nnz_spec, tq=query_tile, bn=pack.block, mixed=mixed)
         # the fused result tuple materializes in one device_get
-        DISPATCH_STATS.host_transfers += 1
-        indptr_pad, fi, fd, total_spec = jax.device_get(out)
+        indptr_pad, fi, fd, total_spec = _to_host(out)
         total = int(indptr_pad[m])
         spec["nnz_cap"] = max(nnz_spec, _ops.csr_capacity(total))
+        fits = int(total_spec) + 1 <= nnz_spec
+        _note_cells(cells, 2 if fits else 1)  # the compact ran if it fit
         if total + 1 <= nnz_spec and int(total_spec) == int(indptr_pad[-1]):
-            indptr = indptr_pad[:m + 1].astype(np.int64)
-            counts = np.diff(indptr)
-            if total == 0:
-                return (indptr, counts, np.zeros(0, np.int64),
-                        np.zeros(0, np.float32))
-            fi = fi[:total]
-            if not (fi >= 0).all():
-                raise RuntimeError("CSR pass-1/pass-2 disagreement (packed)")
-            return (indptr, counts, ids.reshape(-1)[fi],
-                    np.ascontiguousarray(fd[:total]))
+            with TraceAnnotation("snn.assemble"):
+                indptr = indptr_pad[:m + 1].astype(np.int64)
+                counts = np.diff(indptr)
+                if total == 0:
+                    return (indptr, counts, np.zeros(0, np.int64),
+                            np.zeros(0, np.float32))
+                fi = fi[:total]
+                if not (fi >= 0).all():
+                    raise RuntimeError(
+                        "CSR pass-1/pass-2 disagreement (packed)")
+                return (indptr, counts, ids.reshape(-1)[fi],
+                        np.ascontiguousarray(fd[:total]))
         # speculation overflow: fall through to the exact-sized classic path
 
     # ---- pass 1: ONE stacked count launch --------------------------------
-    DISPATCH_STATS.kernel_launches += 1
-    per = backend.snn_count_stacked(qp, aqp, rp, thp, xs, al, hn, pq, px,
-                                    tq=query_tile, bn=pack.block,
-                                    mixed=mixed)
+    with _Launch("snn_count_stacked"):
+        per = backend.snn_count_stacked(qp, aqp, rp, thp, xs, al, hn, pq, px,
+                                        tq=query_tile, bn=pack.block,
+                                        mixed=mixed)
 
     # ---- device prefix sums + the one pass-boundary sync -----------------
-    DISPATCH_STATS.kernel_launches += 1
-    _, indptr_dev, offsets_dev = _ref.stacked_prefix(per)
-    DISPATCH_STATS.host_transfers += 1
-    indptr_pad = np.asarray(indptr_dev)  # (m_pad + 1,) int32
+    with _Launch("stacked_prefix"):
+        _, indptr_dev, offsets_dev = _ref.stacked_prefix(per)
+    indptr_pad = _to_host(indptr_dev)  # (m_pad + 1,) int32
     total = int(indptr_pad[m])
     # seed/ratchet the speculation capacity for the next batch of this shape
     spec["nnz_cap"] = max(spec.get("nnz_cap", 0), _ops.csr_capacity(total))
     indptr = indptr_pad[:m + 1].astype(np.int64)
     counts = np.diff(indptr)
     if total == 0:
+        _note_cells(cells, 1)
         return indptr, counts, np.zeros(0, np.int64), np.zeros(0, np.float32)
 
     # ---- pass 2: ONE stacked compaction launch ---------------------------
     cap = _ops.csr_capacity(total)
-    DISPATCH_STATS.kernel_launches += 1
-    fi, fd = backend.snn_compact_stacked(
-        qp, aqp, rp, thp, offsets_dev, xs, al, hn, pq, px,
-        nnz=cap, tq=query_tile, bn=pack.block)
-    DISPATCH_STATS.host_transfers += 2
-    fi = np.asarray(fi)[:total]
+    _note_cells(cells, 2)
+    with _Launch("snn_compact_stacked"):
+        fi, fd = backend.snn_compact_stacked(
+            qp, aqp, rp, thp, offsets_dev, xs, al, hn, pq, px,
+            nnz=cap, tq=query_tile, bn=pack.block)
+    fi = _to_host(fi)[:total]
     if not (fi >= 0).all():
         raise RuntimeError("CSR pass-1/pass-2 disagreement (packed)")
-    flat_ids = ids.reshape(-1)[fi]
-    flat_dh = np.asarray(fd)[:total].copy()
+    flat_dh = _to_host(fd)[:total].copy()
+    with TraceAnnotation("snn.assemble"):
+        flat_ids = ids.reshape(-1)[fi]
     return indptr, counts, flat_ids, flat_dh
 
 
@@ -1813,18 +1922,20 @@ def query_csr_packed(
     """
     from . import snn as _snn  # deferred: snn imports this module lazily too
 
-    xq, aq, r, th, qsq = _snn.prepare_query_predicates(index, q, radius)
-    m = xq.shape[0]
-    qp, aqp, rp, thp, _ = _ops.pad_queries(xq, aq, r, th, tq=query_tile,
-                                           bucket=bucket)
-    pq = _snn.query_extra_projections(index, xq)
-    pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
+    with TraceAnnotation("snn.prep"):
+        xq, aq, r, th, qsq = _snn.prepare_query_predicates(index, q, radius)
+        m = xq.shape[0]
+        qp, aqp, rp, thp, _ = _ops.pad_queries(xq, aq, r, th, tq=query_tile,
+                                               bucket=bucket)
+        pq = _snn.query_extra_projections(index, xq)
+        pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
     indptr, counts, ids, dh = run_csr_packed(
         pack, qp, aqp, rp, thp, m, query_tile=query_tile,
         use_pallas=use_pallas, memory_budget_mb=memory_budget_mb,
         pq=pqp, mixed=mixed, compacted=compacted, fused=fused)
-    return _snn.csr_finalize(index, indptr, ids, dh, xq, qsq, counts,
-                             return_distance, native)
+    with TraceAnnotation("snn.assemble"):
+        return _snn.csr_finalize(index, indptr, ids, dh, xq, qsq, counts,
+                                 return_distance, native)
 
 
 # --------------------------------------------------------------------------- #
@@ -1862,32 +1973,35 @@ def warm_plan(
     query would, so a failure here is raised to the caller: a plan that
     cannot warm could not answer either.
     """
-    if spec_from is not None:
-        pack.adopt_spec(spec_from)
-    S = pack.n_segments
-    if S == 0 or pack.n_pad == 0:
-        return pack
-    d_pad = int(pack.segments[0].xs.shape[1])
-    nonempty = pack.alpha_lo <= pack.alpha_hi
-    aq_seg = np.where(nonempty, pack.alpha_lo, 0.0).astype(np.float32)
-    pq_seg = None
-    if pack.ke:
-        # one box-prune operand per segment too, so the pruned/compacted
-        # oracle executors and the kernels' pq plumbing warm as well
-        pq_seg = np.where(nonempty[:, None],
-                          np.asarray(pack.proj_lo, np.float64),
-                          0.0).astype(np.float32)  # (S, ke)
-    for m_pad in sorted({int(b) for b in m_pads if int(b) > 0}):
-        reps = -(-m_pad // S)  # cycle the per-segment rows to fill the bucket
-        aq = np.tile(aq_seg, reps)[:m_pad]
-        qp = jnp.asarray(np.zeros((m_pad, d_pad), np.float32))
-        rp = jnp.asarray(np.zeros(m_pad, np.float32))
-        thp = jnp.asarray(np.full(m_pad, -_ops.BIG, np.float32))
-        pq = None
-        if pq_seg is not None:
-            pq = np.tile(pq_seg, (reps, 1))[:m_pad].T  # (ke, m_pad)
-        pack.memory_plan(m_pad, query_tile).reserve()
-        run_csr_packed(pack, qp, jnp.asarray(aq), rp, thp, m_pad,
-                       query_tile=query_tile, use_pallas=use_pallas,
-                       pq=pq, mixed=mixed, compacted=compacted, fused=fused)
+    with TraceAnnotation("snn.plan", generation=pack.epoch):
+        if spec_from is not None:
+            pack.adopt_spec(spec_from)
+        S = pack.n_segments
+        if S == 0 or pack.n_pad == 0:
+            return pack
+        d_pad = int(pack.segments[0].xs.shape[1])
+        nonempty = pack.alpha_lo <= pack.alpha_hi
+        aq_seg = np.where(nonempty, pack.alpha_lo, 0.0).astype(np.float32)
+        pq_seg = None
+        if pack.ke:
+            # one box-prune operand per segment too, so the pruned/compacted
+            # oracle executors and the kernels' pq plumbing warm as well
+            pq_seg = np.where(nonempty[:, None],
+                              np.asarray(pack.proj_lo, np.float64),
+                              0.0).astype(np.float32)  # (S, ke)
+        for m_pad in sorted({int(b) for b in m_pads if int(b) > 0}):
+            # cycle the per-segment rows to fill the bucket
+            reps = -(-m_pad // S)
+            aq = np.tile(aq_seg, reps)[:m_pad]
+            qp = jnp.asarray(np.zeros((m_pad, d_pad), np.float32))
+            rp = jnp.asarray(np.zeros(m_pad, np.float32))
+            thp = jnp.asarray(np.full(m_pad, -_ops.BIG, np.float32))
+            pq = None
+            if pq_seg is not None:
+                pq = np.tile(pq_seg, (reps, 1))[:m_pad].T  # (ke, m_pad)
+            pack.memory_plan(m_pad, query_tile).reserve()
+            run_csr_packed(pack, qp, jnp.asarray(aq), rp, thp, m_pad,
+                           query_tile=query_tile, use_pallas=use_pallas,
+                           pq=pq, mixed=mixed, compacted=compacted,
+                           fused=fused)
     return pack

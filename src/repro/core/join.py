@@ -41,6 +41,7 @@ degenerate case of that partition/halo schedule (Raulet et al., PAPERS.md).
 from __future__ import annotations
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..kernels import ops as _ops
 from . import engine as _engine
@@ -364,11 +365,12 @@ def count_pass(pack, xq, aq, qsq, r, *, query_tile: int = 128,
     shrinking active subset each round — bucketed padding keeps that at
     O(log m) compiled shapes instead of one per round.
     """
-    thresh = ((r * r - qsq) / 2.0).astype(np.float32)
-    qp, aqp, rp, thp, m = _ops.pad_queries(xq, aq, r.astype(np.float32),
-                                           thresh, tq=query_tile,
-                                           bucket=bucket)
-    pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
+    with TraceAnnotation("snn.prep"):
+        thresh = ((r * r - qsq) / 2.0).astype(np.float32)
+        qp, aqp, rp, thp, m = _ops.pad_queries(xq, aq, r.astype(np.float32),
+                                               thresh, tq=query_tile,
+                                               bucket=bucket)
+        pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
     return _engine.run_counts_packed(pack, qp, aqp, rp, thp, m,
                                      query_tile=query_tile,
                                      use_pallas=use_pallas,
@@ -395,12 +397,14 @@ def query_counts(index, q, radius, *, block: int = 512,
     (counts run over base + deltas through the cached plan); ``radius`` is
     a scalar or per-query (m,) vector in the native metric.
     """
-    owner, pack = _resolve_pack(index, block)
-    xq, aq, r32, th, qsq = _snn.prepare_query_predicates(owner, q, radius)
-    qp, aqp, rp, thp, m = _ops.pad_queries(xq, aq, r32, th, tq=query_tile,
-                                           bucket=bucket)
-    pq = _snn.query_extra_projections(owner, xq)
-    pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
+    with TraceAnnotation("snn.prep"):
+        owner, pack = _resolve_pack(index, block)
+        xq, aq, r32, th, qsq = _snn.prepare_query_predicates(owner, q,
+                                                             radius)
+        qp, aqp, rp, thp, m = _ops.pad_queries(xq, aq, r32, th,
+                                               tq=query_tile, bucket=bucket)
+        pq = _snn.query_extra_projections(owner, xq)
+        pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
     return _engine.run_counts_packed(pack, qp, aqp, rp, thp, m,
                                      query_tile=query_tile,
                                      use_pallas=use_pallas,

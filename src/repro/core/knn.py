@@ -37,6 +37,11 @@ final radii carry a small relative margin so a float32 boundary rounding
 cannot exclude a true neighbor whose distance sits exactly at the validated
 radius.
 
+`engine.DISPATCH_STATS` counts the loop's count passes (``knn_rounds``),
+the final pass's CSR entries (``knn_candidates``) and the neighbors asked
+for (``knn_wanted``); the host work runs in ``snn.prep`` /
+``snn.knn.round`` / ``snn.assemble`` profiler spans.
+
 Works over a plain `snn.SNNIndex` or a `streaming.StreamingSNNIndex`
 snapshot (base + LSM deltas through the same plan the radius path uses).
 For mips, "k nearest" means the k largest inner products (the lifted
@@ -46,6 +51,7 @@ transforms are monotone too, so kNN in index space is kNN in the metric.
 from __future__ import annotations
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..kernels import ops as _ops
 from . import engine as _engine
@@ -205,53 +211,62 @@ def query_knn(
       (k > n), the tail columns carry id -1 and distance +inf.
       With ``return_distance`` the result is ``(indices, distances)``.
     """
-    owner, parts, pack = _resolve(index, block)
-    tq_ = _metrics.transform_query(np.asarray(q), owner.metric)
-    xq = (tq_ - owner.mu[None, :]).astype(np.float32)
-    m = xq.shape[0]
-    n_total = sum(p.n for p in parts)
+    with TraceAnnotation("snn.prep"):
+        owner, parts, pack = _resolve(index, block)
+        tq_ = _metrics.transform_query(np.asarray(q), owner.metric)
+        xq = (tq_ - owner.mu[None, :]).astype(np.float32)
+        m = xq.shape[0]
+        n_total = sum(p.n for p in parts)
 
-    k_arr = np.asarray(k, np.int64)
-    k_arr = np.full(m, int(k_arr), np.int64) if k_arr.ndim == 0 else k_arr
-    if k_arr.shape != (m,):
-        raise ValueError(f"k must be a scalar or per-query ({m},) vector; "
-                         f"got shape {k_arr.shape}")
-    if (k_arr < 0).any():
-        raise ValueError("k must be >= 0")
-    K_out = int(k_arr.max()) if m else 0
-    out_idx = np.full((m, K_out), -1, np.int64)
-    out_sq = np.full((m, K_out), np.inf, np.float64)
-    k_eff = np.minimum(k_arr, n_total)
+        k_arr = np.asarray(k, np.int64)
+        k_arr = np.full(m, int(k_arr), np.int64) if k_arr.ndim == 0 \
+            else k_arr
+        if k_arr.shape != (m,):
+            raise ValueError(f"k must be a scalar or per-query ({m},) "
+                             f"vector; got shape {k_arr.shape}")
+        if (k_arr < 0).any():
+            raise ValueError("k must be >= 0")
+        K_out = int(k_arr.max()) if m else 0
+        out_idx = np.full((m, K_out), -1, np.int64)
+        out_sq = np.full((m, K_out), np.inf, np.float64)
+        k_eff = np.minimum(k_arr, n_total)
 
     if m and n_total and k_eff.max() > 0:
-        # the predicate inputs the engine sees (float32, computed ONCE) and
-        # their float64 twins for the seed/cap arithmetic
-        aq = (xq @ owner.v1).astype(np.float32)
-        pq = _snn.query_extra_projections(owner, xq)
-        qsq32 = np.einsum("ij,ij->i", xq, xq)
-        aq64 = (xq.astype(np.float64) @ owner.v1.astype(np.float64))
-        qsq64 = np.einsum("ij,ij->i", xq.astype(np.float64), xq)
-        # diameter bound in centered index space: every distance is at most
-        # max ||x|| + ||q||; inflated so float32 boundary rounding at the
-        # cap still admits all n points (the loop's termination guarantee)
-        max_half = max((float(np.max(p.half_norms)) if p.n else 0.0)
-                       for p in parts)
-        ub = (np.sqrt(2.0 * max(max_half, 0.0)) + np.sqrt(qsq64)) * 1.01 \
-            + 1e-6
+        stats = _engine.DISPATCH_STATS
+        stats.knn_wanted += int(k_eff.sum())
+        with TraceAnnotation("snn.prep"):
+            # the predicate inputs the engine sees (float32, computed ONCE)
+            # and their float64 twins for the seed/cap arithmetic
+            aq = (xq @ owner.v1).astype(np.float32)
+            pq = _snn.query_extra_projections(owner, xq)
+            qsq32 = np.einsum("ij,ij->i", xq, xq)
+            aq64 = (xq.astype(np.float64) @ owner.v1.astype(np.float64))
+            qsq64 = np.einsum("ij,ij->i", xq.astype(np.float64), xq)
+            # diameter bound in centered index space: every distance is at
+            # most max ||x|| + ||q||; inflated so float32 boundary rounding
+            # at the cap still admits all n points (the loop's termination
+            # guarantee)
+            max_half = max((float(np.max(p.half_norms)) if p.n else 0.0)
+                           for p in parts)
+            ub = (np.sqrt(2.0 * max(max_half, 0.0)) + np.sqrt(qsq64)) \
+                * 1.01 + 1e-6
 
-        r = np.minimum(
-            np.maximum(_seed_radii(parts, aq64, np.maximum(k_eff, 1)),
-                       _sample_estimate(parts, xq, np.maximum(k_eff, 1),
-                                        n_total)),
-            ub)
-        active = np.nonzero(k_eff > 0)[0]
-        for _ in range(max_rounds):
-            counts = _count_pass(pack, xq[active], aq[active], qsq32[active],
-                                 r[active], query_tile=query_tile,
-                                 use_pallas=use_pallas,
-                                 memory_budget_mb=memory_budget_mb,
-                                 pq=None if pq is None else pq[:, active],
-                                 mixed=mixed, bucket=bucket)
+            r = np.minimum(
+                np.maximum(_seed_radii(parts, aq64, np.maximum(k_eff, 1)),
+                           _sample_estimate(parts, xq, np.maximum(k_eff, 1),
+                                            n_total)),
+                ub)
+            active = np.nonzero(k_eff > 0)[0]
+        for rnd in range(max_rounds):
+            with TraceAnnotation("snn.knn.round", round=rnd,
+                                 active=int(active.size)):
+                counts = _count_pass(
+                    pack, xq[active], aq[active], qsq32[active], r[active],
+                    query_tile=query_tile, use_pallas=use_pallas,
+                    memory_budget_mb=memory_budget_mb,
+                    pq=None if pq is None else pq[:, active],
+                    mixed=mixed, bucket=bucket)
+            stats.knn_rounds += 1
             short = counts < k_eff[active]
             if not short.any():
                 break
@@ -267,40 +282,45 @@ def query_knn(
         # final count->compact on the converged radii (+margin); the engine
         # recounts internally with the same predicate pipeline, so every row
         # is complete — the loop above was advisory, not load-bearing
-        r_fin = np.where(k_eff > 0, r * (1.0 + _RADIUS_MARGIN), 0.0)
-        # k == 0 rows must match nothing at all (not even themselves)
-        r_fin[k_eff == 0] = -1.0
-        thresh = ((r_fin * r_fin - qsq32) / 2.0).astype(np.float32)
-        thresh[k_eff == 0] = np.float32(-_ops.BIG)
-        qp, aqp, rp, thp, _ = _ops.pad_queries(
-            xq, aq, r_fin.astype(np.float32), thresh, tq=query_tile,
-            bucket=bucket)
-        pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
+        with TraceAnnotation("snn.prep"):
+            r_fin = np.where(k_eff > 0, r * (1.0 + _RADIUS_MARGIN), 0.0)
+            # k == 0 rows must match nothing at all (not even themselves)
+            r_fin[k_eff == 0] = -1.0
+            thresh = ((r_fin * r_fin - qsq32) / 2.0).astype(np.float32)
+            thresh[k_eff == 0] = np.float32(-_ops.BIG)
+            qp, aqp, rp, thp, _ = _ops.pad_queries(
+                xq, aq, r_fin.astype(np.float32), thresh, tq=query_tile,
+                bucket=bucket)
+            pqp = None if pq is None else _ops.pad_components(pq,
+                                                              qp.shape[0])
         indptr, _, flat_ids, _ = _engine.run_csr_packed(
             pack, qp, aqp, rp, thp, m, query_tile=query_tile,
             use_pallas=use_pallas, memory_budget_mb=memory_budget_mb,
             pq=pqp, mixed=mixed)
+        stats.knn_candidates += int(indptr[-1])
 
         # float64 distance refinement on the survivors: the half-norm trick
         # loses low bits to cancellation exactly where kNN ordering needs
         # them; recomputing ||x - q||^2 from the candidate vectors keeps the
         # select stable against float32 near-ties
-        vecs = _fetch_rows(parts, flat_ids).astype(np.float64)
-        rows = np.repeat(np.arange(m), np.diff(indptr))
-        diff = vecs - xq.astype(np.float64)[rows]
-        sq = np.einsum("ij,ij->i", diff, diff)
-        for i in range(m):
-            s, e = int(indptr[i]), int(indptr[i + 1])
-            kk = min(int(k_eff[i]), e - s)
-            if kk == 0:
-                continue
-            order = np.lexsort((flat_ids[s:e], sq[s:e]))[:kk]
-            out_idx[i, :kk] = flat_ids[s:e][order]
-            out_sq[i, :kk] = sq[s:e][order]
+        with TraceAnnotation("snn.assemble"):
+            vecs = _fetch_rows(parts, flat_ids).astype(np.float64)
+            rows = np.repeat(np.arange(m), np.diff(indptr))
+            diff = vecs - xq.astype(np.float64)[rows]
+            sq = np.einsum("ij,ij->i", diff, diff)
+            for i in range(m):
+                s, e = int(indptr[i]), int(indptr[i + 1])
+                kk = min(int(k_eff[i]), e - s)
+                if kk == 0:
+                    continue
+                order = np.lexsort((flat_ids[s:e], sq[s:e]))[:kk]
+                out_idx[i, :kk] = flat_ids[s:e][order]
+                out_sq[i, :kk] = sq[s:e][order]
 
     if not return_distance:
         return out_idx
     if not native:
         return out_idx, out_sq
-    return out_idx, _metrics.native_knn_distances(out_idx, out_sq,
-                                                  owner.metric, owner.xi, tq_)
+    with TraceAnnotation("snn.assemble"):
+        return out_idx, _metrics.native_knn_distances(
+            out_idx, out_sq, owner.metric, owner.xi, tq_)

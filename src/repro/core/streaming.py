@@ -46,6 +46,7 @@ from __future__ import annotations
 import threading
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import engine as _engine
 from . import metrics as _metrics
@@ -241,11 +242,12 @@ class StreamingSNNIndex:
         if not self._warm:
             return tuple(None for _ in parts), None
         prev_plan = self._state[2]
-        segs = tuple(_engine.segment_from_index(p, block=self.block)
-                     for p in parts)
-        plan = _engine.SegmentPack.build(list(segs),
-                                         epoch=self._generation + 1)
-        self._prime(plan, spec_from=prev_plan)
+        with TraceAnnotation("snn.plan", generation=self._generation + 1):
+            segs = tuple(_engine.segment_from_index(p, block=self.block)
+                         for p in parts)
+            plan = _engine.SegmentPack.build(list(segs),
+                                             epoch=self._generation + 1)
+            self._prime(plan, spec_from=prev_plan)
         return segs, plan
 
     def plan_bytes(self) -> int:
@@ -516,13 +518,14 @@ class StreamingSNNIndex:
         with self._lock:
             parts, segs, plan = self._state
         if any(s is None for s in segs) or plan is None:
-            segs = tuple(
-                s if s is not None
-                else _engine.segment_from_index(p, block=self.block)
-                for p, s in zip(parts, segs))
-            if plan is None:
-                plan = _engine.SegmentPack.build(list(segs),
-                                                 epoch=self._generation)
+            with TraceAnnotation("snn.plan", generation=self._generation):
+                segs = tuple(
+                    s if s is not None
+                    else _engine.segment_from_index(p, block=self.block)
+                    for p, s in zip(parts, segs))
+                if plan is None:
+                    plan = _engine.SegmentPack.build(list(segs),
+                                                     epoch=self._generation)
             with self._lock:
                 if self._state[0] is parts:
                     self._state = (parts, segs, plan)

@@ -7,8 +7,13 @@ This module is the mechanics under `serving.server.SNNServer` and
   (``slo_ms``, defaulting to ``SNNConfig.serve_slo_ms``) and a ``tenant``
   name; a response records how its latency split into queue delay (submit →
   batch flush) and service time (the fused engine execution), plus the
-  index ``generation`` it was answered on and an ``error`` string when the
+  index ``generation`` it was answered on, the ``batch`` (per-runtime
+  sequence number) that served it and an ``error`` string when the
   runtime could not serve it (instead of silently timing the caller out).
+  The admission loop and the executors open ``snn.*`` profiler spans
+  (``snn.await_request``, ``snn.admit``, ``snn.batch`` with the same
+  ``batch`` number, ``snn.prep``, ``snn.assemble``), recorded only while
+  the JAX profiler runs.
 * `ServiceClock` — the per-batch service-time EWMA the deadline-aware
   admission policy subtracts from the oldest request's remaining budget.
 * `collect_batch` — one admission-loop iteration.  ``serve_policy ==
@@ -41,6 +46,7 @@ import time
 import traceback
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.snn_default import SNNConfig
 from ..core import metrics as _metrics
@@ -114,6 +120,9 @@ class Response:
     service_ms: float = 0.0
     # index generation the answer was computed on (-1: runtime predates it)
     generation: int = -1
+    # sequence number of the tenant runtime's batch that served the request
+    # (the ``batch`` of its ``snn.batch`` profiler span; -1: no batch)
+    batch: int = -1
     # set when the runtime could NOT serve the request (degraded path with
     # no equivalent for this kind, executor failure, unknown tenant):
     # indices/sq_dists are empty and the caller should treat this as a fast
@@ -170,37 +179,43 @@ def collect_batch(q: "queue.Queue[Request]", cfg: SNNConfig,
         # legacy fixed window: the batch closes serve_timeout_ms after the
         # iteration starts, whether or not anything arrived early
         batch: list[Request] = []
-        deadline = time.monotonic() + cfg.serve_timeout_ms / 1e3
-        while len(batch) < cfg.serve_batch:
-            tmo = deadline - time.monotonic()
-            if tmo <= 0:
-                break
-            try:
-                batch.append(q.get(timeout=tmo))
-            except queue.Empty:
-                break
+        with TraceAnnotation("snn.admit") as span:
+            deadline = time.monotonic() + cfg.serve_timeout_ms / 1e3
+            while len(batch) < cfg.serve_batch:
+                tmo = deadline - time.monotonic()
+                if tmo <= 0:
+                    break
+                try:
+                    batch.append(q.get(timeout=tmo))
+                except queue.Empty:
+                    break
+            span.set_metadata(requests=len(batch))
         return batch
     # deadline-aware continuous batching: block ONLY for the first request
-    try:
-        first = q.get(timeout=poll_s)
-    except queue.Empty:
-        return []
-    batch = [first]
-    flush_at = request_deadline(first, cfg)
-    est = clock.estimate() if clock is not None else 0.0
-    while len(batch) < cfg.serve_batch:
-        # the OLDEST request governs: once its remaining budget no longer
-        # covers the expected service time, flush whatever is fused so far
-        # (an already-expired budget flushes the first request alone)
-        if flush_at - time.monotonic() - est <= 0.0:
-            break
+    with TraceAnnotation("snn.await_request"):
         try:
-            # non-blocking: an empty queue means light load — flush NOW
-            # instead of holding the batch open for a window that only
-            # adds queueing latency
-            batch.append(q.get_nowait())
+            first = q.get(timeout=poll_s)
         except queue.Empty:
-            break
+            return []
+    with TraceAnnotation("snn.admit") as span:
+        batch = [first]
+        flush_at = request_deadline(first, cfg)
+        est = clock.estimate() if clock is not None else 0.0
+        while len(batch) < cfg.serve_batch:
+            # the OLDEST request governs: once its remaining budget no
+            # longer covers the expected service time, flush whatever is
+            # fused so far (an already-expired budget flushes the first
+            # request alone)
+            if flush_at - time.monotonic() - est <= 0.0:
+                break
+            try:
+                # non-blocking: an empty queue means light load — flush NOW
+                # instead of holding the batch open for a window that only
+                # adds queueing latency
+                batch.append(q.get_nowait())
+            except queue.Empty:
+                break
+        span.set_metadata(requests=len(batch))
     return batch
 
 
@@ -239,6 +254,7 @@ class TenantRuntime:
         # batch-local context (valid during one run_batch call)
         self._t_svc = 0.0
         self._gen = -1
+        self._batch = -1  # sequence number of the batch being served
         self._stored: set[int] = set()
         self._emit_fn = None
 
@@ -300,34 +316,41 @@ class TenantRuntime:
         t_svc = time.monotonic()
         self._t_svc = t_svc
         self._gen = self.index.generation
+        self._batch += 1
         self._stored = set()
         self._emit_fn = store
         failures = []
-        try:
-            knn_sel = [i for i, r in enumerate(batch)
-                       if r.kind == "snn-knn"]
-            csr_sel = [i for i, r in enumerate(batch)
-                       if r.kind != "snn-knn"]
-            for sel, serve in ((csr_sel, self._serve_csr),
-                               (knn_sel, self._respond_knn)):
-                if not sel:
-                    continue
-                try:
-                    serve(batch, sel)
-                except Exception as e:
-                    traceback.print_exc()
-                    failures.append(f"{type(e).__name__}: {e}")
-        finally:
-            # the no-silent-drop guarantee: whatever failed above, every
-            # request's caller gets a fast error instead of a timeout
-            why = "; ".join(failures) or "see server log"
-            for r in batch:
-                if r.id not in self._stored:
-                    self._emit_error(r, f"{r.kind} request could not be "
-                                     f"served (executor failure: {why})")
-            if clock is not None:
-                clock.observe(time.monotonic() - t_svc)
-            self._emit_fn = None
+        with TraceAnnotation("snn.batch", batch=self._batch,
+                             requests=len(batch)) as span:
+            try:
+                kinds = [r.kind for r in batch]
+                if span.is_enabled():
+                    span.set_metadata(rows=sum(r.rows for r in batch),
+                                      kinds="+".join(sorted(set(kinds))))
+                knn_sel = [i for i, k in enumerate(kinds) if k == "snn-knn"]
+                csr_sel = [i for i, k in enumerate(kinds) if k != "snn-knn"]
+                for sel, serve in ((csr_sel, self._serve_csr),
+                                   (knn_sel, self._respond_knn)):
+                    if not sel:
+                        continue
+                    try:
+                        serve(batch, sel)
+                    except Exception as e:
+                        traceback.print_exc()
+                        failures.append(f"{type(e).__name__}: {e}")
+            finally:
+                # the no-silent-drop guarantee: whatever failed above,
+                # every request's caller gets a fast error instead of a
+                # timeout
+                why = "; ".join(failures) or "see server log"
+                for r in batch:
+                    if r.id not in self._stored:
+                        self._emit_error(r, f"{r.kind} request could not "
+                                         f"be served (executor failure: "
+                                         f"{why})")
+                if clock is not None:
+                    clock.observe(time.monotonic() - t_svc)
+                self._emit_fn = None
 
     def _serve_csr(self, batch, csr_sel) -> None:
         """The CSR family on the exact path, or — only when
@@ -365,12 +388,13 @@ class TenantRuntime:
             queue_delay_ms=max(0.0, (self._t_svc - t0) * 1e3)
             if req._t0 else 0.0,
             service_ms=(now - self._t_svc) * 1e3,
-            generation=self._gen))
+            generation=self._gen, batch=self._batch))
 
     def _emit_error(self, req: Request, message: str) -> None:
         self._stored.add(req.id)
         resp = error_response(req, message)
         resp.generation = self._gen
+        resp.batch = self._batch
         if req._t0:
             resp.queue_delay_ms = max(0.0, (self._t_svc - req._t0) * 1e3)
         self._emit_fn(resp)
@@ -437,36 +461,40 @@ class TenantRuntime:
         """
         cfg = self.cfg
         index = self.index
-        rev_thr = rev_cover = None
-        if any(batch[bi].kind == "snn-reverse" for bi in sel):
-            _, rev_thr, rev_cover = self._reverse_tables()
-        spans, qparts, rparts = [], [], []
-        row0 = 0
-        for bi in sel:
-            r = batch[bi]
-            q = np.asarray(r.query, np.float32)
-            q2 = q[None, :] if q.ndim == 1 else q
-            mi = q2.shape[0]
-            if r.kind == "snn-reverse":
-                rv = np.full(mi, rev_cover, np.float64)
-            else:
-                rv = _metrics.broadcast_radius(r.radius, mi)
-            qparts.append(q2)
-            rparts.append(rv)
-            spans.append((bi, row0, mi))
-            row0 += mi
-        qs = np.concatenate(qparts, axis=0)
-        radii = np.concatenate(rparts)
-        if cfg.serve_bucket:
-            self._buckets.add(int(_ops.bucket_rows(row0, cfg.query_tile)))
+        with TraceAnnotation("snn.prep"):
+            rev_thr = rev_cover = None
+            if any(batch[bi].kind == "snn-reverse" for bi in sel):
+                _, rev_thr, rev_cover = self._reverse_tables()
+            spans, qparts, rparts = [], [], []
+            row0 = 0
+            for bi in sel:
+                r = batch[bi]
+                q = np.asarray(r.query, np.float32)
+                q2 = q[None, :] if q.ndim == 1 else q
+                mi = q2.shape[0]
+                if r.kind == "snn-reverse":
+                    rv = np.full(mi, rev_cover, np.float64)
+                else:
+                    rv = _metrics.broadcast_radius(r.radius, mi)
+                qparts.append(q2)
+                rparts.append(rv)
+                spans.append((bi, row0, mi))
+                row0 += mi
+            qs = np.concatenate(qparts, axis=0)
+            radii = np.concatenate(rparts)
+            if cfg.serve_bucket:
+                self._buckets.add(int(_ops.bucket_rows(row0,
+                                                       cfg.query_tile)))
         if (cfg.serve_count_pass
                 and all(batch[bi].kind == "snn-count" for bi in sel)):
             counts = index.query_counts_device(
                 qs, radii, query_tile=cfg.query_tile,
                 use_pallas=cfg.backend, bucket=cfg.serve_bucket)
-            for bi, s, mi in spans:
-                self._emit(batch[bi], indices=_EMPTY_I, sq_dists=_EMPTY_F,
-                           counts=counts[s:s + mi].copy())
+            with TraceAnnotation("snn.assemble"):
+                for bi, s, mi in spans:
+                    self._emit(batch[bi], indices=_EMPTY_I,
+                               sq_dists=_EMPTY_F,
+                               counts=counts[s:s + mi].copy())
             return
         csr = index.query_radius_csr(qs, radii,
                                      query_tile=cfg.query_tile,
@@ -474,46 +502,52 @@ class TenantRuntime:
                                      packed=cfg.serve_packed,
                                      use_pallas=cfg.backend,
                                      bucket=cfg.serve_bucket)
-        for bi, s, mi in spans:
-            r = batch[bi]
-            # copies throughout: CSR rows are views into the batch-wide flat
-            # arrays, and a Response parked in _results must not pin them
-            if r.kind == "snn-count":
-                cnt = (csr.indptr[s + 1:s + mi + 1]
-                       - csr.indptr[s:s + mi])
-                self._emit(r, indices=_EMPTY_I, sq_dists=_EMPTY_F,
-                           counts=cnt.copy())
-            elif r.kind == "snn-join":
-                lo, hi = csr.indptr[s], csr.indptr[s + mi]
-                self._emit(r, indices=np.array(csr.indices[lo:hi]),
-                           sq_dists=np.array(csr.distances[lo:hi]),
-                           indptr=(csr.indptr[s:s + mi + 1] - lo).copy())
-            elif r.kind == "snn-reverse":
-                if cfg.metric == "mips":
-                    xi = index.base.xi
-                    qsq = np.einsum("ij,ij->i",
-                                    np.asarray(qs[s:s + mi], np.float64),
-                                    np.asarray(qs[s:s + mi], np.float64))
-                    offs = xi * xi + qsq
-                else:
-                    offs = np.zeros(mi)
-                parts_i, parts_d = [], []
-                for t in range(mi):
-                    ids, sq = csr.row(s + t)
-                    fi, fd = self._filter_reverse_row(ids, sq, rev_thr,
-                                                      offs[t])
-                    parts_i.append(fi)
-                    parts_d.append(fd)
-                indptr = np.zeros(mi + 1, np.int64)
-                np.cumsum([p.size for p in parts_i], out=indptr[1:])
-                self._emit(r, indices=np.concatenate(parts_i),
-                           sq_dists=np.concatenate(parts_d),
-                           indptr=(indptr if np.asarray(r.query).ndim == 2
-                                   else None))
-            else:  # snn-radius
-                idx, sq = csr.row(s)
-                self._emit(r, indices=np.array(idx),
-                           sq_dists=np.array(sq))
+        with TraceAnnotation("snn.assemble"):
+            for bi, s, mi in spans:
+                self._emit_csr_rows(batch[bi], csr, qs, s, mi, rev_thr)
+
+    def _emit_csr_rows(self, r, csr, qs, s, mi, rev_thr) -> None:
+        """Answer one request with its rows ``[s, s + mi)`` of the fused
+        batch's CSR."""
+        cfg = self.cfg
+        # copies throughout: CSR rows are views into the batch-wide flat
+        # arrays, and a Response parked in _results must not pin them
+        if r.kind == "snn-count":
+            cnt = (csr.indptr[s + 1:s + mi + 1]
+                   - csr.indptr[s:s + mi])
+            self._emit(r, indices=_EMPTY_I, sq_dists=_EMPTY_F,
+                       counts=cnt.copy())
+        elif r.kind == "snn-join":
+            lo, hi = csr.indptr[s], csr.indptr[s + mi]
+            self._emit(r, indices=np.array(csr.indices[lo:hi]),
+                       sq_dists=np.array(csr.distances[lo:hi]),
+                       indptr=(csr.indptr[s:s + mi + 1] - lo).copy())
+        elif r.kind == "snn-reverse":
+            if cfg.metric == "mips":
+                xi = self.index.base.xi
+                qsq = np.einsum("ij,ij->i",
+                                np.asarray(qs[s:s + mi], np.float64),
+                                np.asarray(qs[s:s + mi], np.float64))
+                offs = xi * xi + qsq
+            else:
+                offs = np.zeros(mi)
+            parts_i, parts_d = [], []
+            for t in range(mi):
+                ids, sq = csr.row(s + t)
+                fi, fd = self._filter_reverse_row(ids, sq, rev_thr,
+                                                  offs[t])
+                parts_i.append(fi)
+                parts_d.append(fd)
+            indptr = np.zeros(mi + 1, np.int64)
+            np.cumsum([p.size for p in parts_i], out=indptr[1:])
+            self._emit(r, indices=np.concatenate(parts_i),
+                       sq_dists=np.concatenate(parts_d),
+                       indptr=(indptr if np.asarray(r.query).ndim == 2
+                               else None))
+        else:  # snn-radius
+            idx, sq = csr.row(s)
+            self._emit(r, indices=np.array(idx),
+                       sq_dists=np.array(sq))
 
     def _respond_fixed(self, batch, sel):
         """Legacy fixed-shape path: K-bounded responses, truncated flag.
@@ -544,14 +578,16 @@ class TenantRuntime:
         (the radius paths' ``sq_dists`` convention), trimmed to each
         request's k.
         """
-        qs = np.stack([np.asarray(batch[bi].query, np.float32)
-                       for bi in sel])
-        ks = np.asarray([batch[bi].k for bi in sel], np.int64)
+        with TraceAnnotation("snn.prep"):
+            qs = np.stack([np.asarray(batch[bi].query, np.float32)
+                           for bi in sel])
+            ks = np.asarray([batch[bi].k for bi in sel], np.int64)
         idx, sq = self.index.query_knn(qs, ks, native=False,
                                        query_tile=self.cfg.query_tile,
                                        use_pallas=self.cfg.backend,
                                        bucket=self.cfg.serve_bucket)
-        for j, bi in enumerate(sel):
-            found = idx[j, :ks[j]] >= 0
-            self._emit(batch[bi], indices=idx[j, :ks[j]][found],
-                       sq_dists=sq[j, :ks[j]][found])
+        with TraceAnnotation("snn.assemble"):
+            for j, bi in enumerate(sel):
+                found = idx[j, :ks[j]] >= 0
+                self._emit(batch[bi], indices=idx[j, :ks[j]][found],
+                           sq_dists=sq[j, :ks[j]][found])
