@@ -79,8 +79,8 @@ _REAL = _ops.BIG / 2
 # Dispatch instrumentation                                                     #
 # --------------------------------------------------------------------------- #
 _STAT_FIELDS = ("kernel_launches", "host_transfers", "jit_compiles",
-                "bytes_planned", "grid_cells", "live_cells", "knn_rounds",
-                "knn_candidates", "knn_wanted")
+                "bytes_planned", "grid_cells", "live_cells", "placed_runs",
+                "knn_rounds", "knn_candidates", "knn_wanted")
 
 
 class _StatCounters:
@@ -113,16 +113,21 @@ class DispatchStats(threading.local):
     query tile, db block) cells of every stacked pass a device lane runs,
     and ``live_cells`` those whose sorted window the kernel finds hit
     (`_count_cells`): the rest skip the MXU but still fetch their blocks.
-    ``knn_rounds`` counts the kNN expansion loop's count passes,
-    ``knn_candidates`` the CSR entries of its final pass and
-    ``knn_wanted`` the neighbors asked for (sum of each query's k, capped
-    at n), so candidates per wanted neighbor is the final pass's excess
-    (`core.knn.query_knn`).
+    ``placed_runs`` counts the (query row, db block) runs pass 2 placed
+    (`_placed_runs`): the row visits of the compaction's row loop that do
+    work, so over ``tq`` x the pass-2 live cells it is the share of a
+    tile's rows that a live cell places.  ``knn_rounds`` counts the kNN
+    expansion loop's count passes, ``knn_candidates`` the CSR entries of
+    its final pass and ``knn_wanted`` the neighbors asked for (sum of each
+    query's k, capped at n), so candidates per wanted neighbor is the
+    final pass's excess (`core.knn.query_knn`).
 
     Readers: the on-chip benchmark's metrics (``bench/metrics``:
     ``launches_per_row`` reads ``kernel_launches``, ``live_cells`` and
     ``knn_candidates`` read the fields of their names, all over the
-    measured window's `aggregate`), `kernels.registry.compile_counts`
+    measured window's `aggregate`; no metric reads ``placed_runs``, which
+    the harness prints with the rest of the window's counters as
+    "dispatch in the window"), `kernels.registry.compile_counts`
     beside ``jit_compiles``, the serving registry's memory budget
     (``bytes_planned``), `benchmarks.common.dispatch_counts` and
     ``benchmarks/check_dispatch.py`` (launches and transfers per call), and
@@ -1121,6 +1126,27 @@ def _note_cells(cells: tuple[int, int], passes: int) -> None:
     DISPATCH_STATS.live_cells += passes * cells[1]
 
 
+def _placed_runs(indptr, fi, bn: int) -> int:
+    """(query row, db block) runs in a pass-2 result.
+
+    ``fi`` holds pack-flat positions (``s * n_pad + row``, n_pad a multiple
+    of ``bn``), so ``fi // bn`` names the (segment, db block) of each slot.
+    A run starts at every non-empty row's first slot and wherever the block
+    changes inside a row.  One vectorised pass over the ``indptr[-1]``
+    slots.
+    """
+    nnz = int(indptr[-1])
+    if not nnz:
+        return 0
+    blk = fi[:nnz] // bn
+    new = np.empty(nnz, bool)
+    new[0] = True
+    np.not_equal(blk[1:], blk[:-1], out=new[1:])
+    starts = indptr[:-1]
+    new[starts[starts < nnz]] = True
+    return int(np.count_nonzero(new))
+
+
 def _gather_live_concat(pack: SegmentPack, live_idx: np.ndarray,
                         with_px: bool = False):
     """(xs, alphas, half_norms, ids, sizes[, projs]) of the live segments'
@@ -1803,6 +1829,9 @@ def _execute_stacked(pack: SegmentPack, qp, aqp, rp, thp, m: int,
         spec["nnz_cap"] = max(nnz_spec, _ops.csr_capacity(total))
         fits = int(total_spec) + 1 <= nnz_spec
         _note_cells(cells, 2 if fits else 1)  # the compact ran if it fit
+        if fits:
+            DISPATCH_STATS.placed_runs += _placed_runs(indptr_pad, fi,
+                                                       pack.block)
         if total + 1 <= nnz_spec and int(total_spec) == int(indptr_pad[-1]):
             with TraceAnnotation("snn.assemble"):
                 indptr = indptr_pad[:m + 1].astype(np.int64)
@@ -1845,6 +1874,7 @@ def _execute_stacked(pack: SegmentPack, qp, aqp, rp, thp, m: int,
             qp, aqp, rp, thp, offsets_dev, xs, al, hn, pq, px,
             nnz=cap, tq=query_tile, bn=pack.block)
     fi = _to_host(fi)[:total]
+    DISPATCH_STATS.placed_runs += _placed_runs(indptr, fi, pack.block)
     if not (fi >= 0).all():
         raise RuntimeError("CSR pass-1/pass-2 disagreement (packed)")
     flat_dh = _to_host(fd)[:total].copy()

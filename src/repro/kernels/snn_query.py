@@ -37,7 +37,10 @@ Five entry kernels share the body:
   * ``compact``: pass 2 of the two-pass CSR engine — re-runs the block-pruned
     filter and scatters surviving (sorted-row index, dhalf) pairs directly into
     flat CSR arrays at caller-provided per-query offsets.  No (m, n)
-    intermediate is ever materialized.
+    intermediate is ever materialized.  Each query row's write base lives
+    in SMEM; a cell with survivors copies its per-row counts into SMEM once
+    and places only the rows that have any (`_scatter_cell`), so a row
+    without survivors costs one scalar test.
   * ``count_stacked`` / ``compact_stacked``: the same two passes over a whole
     *stack* of segments at once (`core.engine.SegmentPack`) — the grid grows a
     leading segment axis, so one launch covers every live segment of a
@@ -364,14 +367,18 @@ def _place_run(dest, bits, t_off):
     return local_w, bits_w
 
 
-def _scatter_cell(keep, dhalf, base, col0, idx_ref, dh_ref, dest_scr,
-                  bits_scr):
+def _scatter_cell(keep, dhalf, col0, base_smem, idx_ref, dh_ref, dest_scr,
+                  bits_scr, cnt_scr, cnt_smem):
     """Write one cell's survivors into the flat CSR outputs.
 
-    Survivor j of query row k goes to ``base[k]`` + (survivors before j in
-    this block) — ascending sorted order, so each CSR row is written left
-    to right exactly once across the block loop.  Rows without survivors
-    in this block cost one scalar test.  Returns the (1, tq) per-row counts.
+    Survivor j of query row k goes to ``base_smem[k]`` + (survivors before
+    j in this block) — ascending sorted order, so each CSR row is written
+    left to right exactly once across the block loop.  A cell without
+    survivors skips the row loop.  Otherwise the (1, tq) per-row counts
+    take one local copy into SMEM, and the loop reads each row's count and
+    write base there as scalars: a row without survivors in this block
+    costs one scalar test, a row with survivors one `_place_run` and two
+    window writes, then its base moves past the run.
     """
     tq, bn = keep.shape
     dest = jnp.where(keep, _exclusive_prefix(keep), -1)
@@ -380,17 +387,16 @@ def _scatter_cell(keep, dhalf, base, col0, idx_ref, dh_ref, dest_scr,
         dest_scr[g] = dest[g * 8:(g + 1) * 8]
         bits_scr[g] = bits[g * 8:(g + 1) * 8]
     cnt = jnp.sum(keep.astype(jnp.int32), axis=1)[None, :]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tq), 1)
     sub = jax.lax.broadcasted_iota(jnp.int32, (8, bn), 0)
     slots = (jax.lax.broadcasted_iota(jnp.int32, (16, 128), 0) * 128
              + jax.lax.broadcasted_iota(jnp.int32, (16, 128), 1))
 
     def row(k, carry):
-        n_k = jnp.sum(jnp.where(lane == k, cnt, 0))
+        n_k = cnt_smem[0, k]
 
         @pl.when(n_k > 0)
         def _():
-            b_k = jnp.sum(jnp.where(lane == k, base, 0))
+            b_k = base_smem[0, k]
             t0 = b_k >> _TILE_BITS
             t_off = b_k & (_TILE - 1)
             pick = sub == (k & 7)
@@ -408,14 +414,15 @@ def _scatter_cell(keep, dhalf, base, col0, idx_ref, dh_ref, dest_scr,
             dh_ref[win] = jnp.where(
                 run, jax.lax.bitcast_convert_type(bits_w, jnp.float32),
                 old_d).reshape(2, 8, 128)
+            base_smem[0, k] = b_k + n_k
 
         return carry
 
     @pl.when(jnp.sum(cnt) > 0)
     def _():
+        cnt_scr[...] = cnt
+        pltpu.sync_copy(cnt_scr, cnt_smem)
         jax.lax.fori_loop(0, tq, row, 0)
-
-    return cnt
 
 
 def _compact_outputs(nnz: int, tq: int, bn: int):
@@ -433,9 +440,11 @@ def _compact_outputs(nnz: int, tq: int, bn: int):
     n_tiles = -(-nnz // _TILE) + 1
     out_shape = [jax.ShapeDtypeStruct((n_tiles, 8, 128), jnp.int32),
                  jax.ShapeDtypeStruct((n_tiles, 8, 128), jnp.float32)]
-    scratch = [pltpu.VMEM((1, tq), jnp.int32),             # write cursor
+    scratch = [pltpu.SMEM((1, tq), jnp.int32),             # write bases
                pltpu.VMEM((tq // 8, 8, bn), jnp.int32),    # run ranks
-               pltpu.VMEM((tq // 8, 8, bn), jnp.int32)]    # dhalf bits
+               pltpu.VMEM((tq // 8, 8, bn), jnp.int32),    # dhalf bits
+               pltpu.VMEM((1, tq), jnp.int32),             # cell counts
+               pltpu.SMEM((1, tq), jnp.int32)]             # ... for scalar reads
     # the two resident outputs plus room for the tile operands
     vmem = 2 * n_tiles * _TILE * 4 + (32 << 20)
     return out_shape, scratch, vmem
@@ -447,8 +456,7 @@ def _flat(out_idx, out_dh, nnz: int):
 
 def _compact_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
                     x_ref, al_ref, hn_ref, *rest):
-    pq_ref, px_ref, (idx_ref, dh_ref, cursor_ref, dest_scr, bits_scr) = \
-        _split_rest(rest, 5)
+    pq_ref, px_ref, (idx_ref, dh_ref, base_smem, *scr) = _split_rest(rest, 7)
     qi = pl.program_id(0)
     bi = pl.program_id(1)
     bn = x_ref.shape[0]
@@ -460,7 +468,7 @@ def _compact_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
 
     @pl.when(bi == 0)
     def _():
-        cursor_ref[...] = jnp.zeros_like(cursor_ref)
+        pltpu.sync_copy(off_ref, base_smem)
 
     a_lo = al_ref[0, 0]
     a_hi = al_ref[0, al_ref.shape[1] - 1]
@@ -473,9 +481,7 @@ def _compact_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
             al_ref[...], hn_ref[...],
             None if pq_ref is None else pq_ref[...],
             None if px_ref is None else px_ref[...])
-        cursor_ref[...] += _scatter_cell(
-            keep, dhalf, off_ref[...] + cursor_ref[...], bi * bn,
-            idx_ref, dh_ref, dest_scr, bits_scr)
+        _scatter_cell(keep, dhalf, bi * bn, base_smem, idx_ref, dh_ref, *scr)
 
 
 @functools.partial(jax.jit, static_argnames=("nnz", "tq", "bn", "interpret"))
@@ -494,8 +500,9 @@ def snn_compact(q, aq, r, thresh, offsets, xs, alphas, half_norms,
     box-tightened predicate, preserving the count/compact agreement.
 
     Both grid dims are sequential: every cell scatters into the same flat
-    output block, and a VMEM cursor carries each query's running write position
-    across db blocks.
+    output block, and an SMEM write base per query row, set from
+    ``offsets`` at the tile's first block, carries the row's running write
+    position across db blocks.
 
     Memory: the flat outputs live in one VMEM block (8 bytes per slot), so
     one call holds at most `MAX_NNZ` slots and raises beyond it; callers
@@ -599,8 +606,7 @@ def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
     Offsets are per (segment, query) — the global CSR base plus the
     segment-axis exclusive prefix, both computed on device.
     """
-    pq_ref, px_ref, (idx_ref, dh_ref, cursor_ref, dest_scr, bits_scr) = \
-        _split_rest(rest, 5)
+    pq_ref, px_ref, (idx_ref, dh_ref, base_smem, *scr) = _split_rest(rest, 7)
     si = pl.program_id(0)
     qi = pl.program_id(1)
     bi = pl.program_id(2)
@@ -614,7 +620,7 @@ def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
 
     @pl.when(bi == 0)
     def _():
-        cursor_ref[...] = jnp.zeros_like(cursor_ref)
+        pltpu.sync_copy(off_ref.at[0], base_smem)
 
     a_lo = al_ref[0, 0, 0]
     a_hi = al_ref[0, 0, al_ref.shape[2] - 1]
@@ -627,9 +633,8 @@ def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
             al_ref[0], hn_ref[0],
             None if pq_ref is None else pq_ref[...],
             None if px_ref is None else px_ref[0])
-        cursor_ref[...] += _scatter_cell(
-            keep, dhalf, off_ref[0] + cursor_ref[...], si * n_pad + bi * bn,
-            idx_ref, dh_ref, dest_scr, bits_scr)
+        _scatter_cell(keep, dhalf, si * n_pad + bi * bn, base_smem, idx_ref,
+                      dh_ref, *scr)
 
 
 @functools.partial(jax.jit, static_argnames=("nnz", "tq", "bn", "interpret"))
@@ -645,8 +650,8 @@ def snn_compact_stacked(q, aq, r, thresh, offsets, xs, alphas, half_norms,
     dhalf (nnz,) f32); same capacity and -1/+BIG conventions as
     `snn_compact`.
     All three grid dims are sequential: every cell scatters into the same
-    flat output block, with the VMEM cursor carrying each query's running
-    write position across a segment's db blocks.
+    flat output block, with the SMEM write bases carrying each query's
+    running write position across a segment's db blocks.
     """
     m, d = q.shape
     n_seg, n, _ = xs.shape

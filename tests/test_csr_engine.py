@@ -142,6 +142,99 @@ def test_compact_kernel_matches_ref(n, d, m, radius):
     np.testing.assert_allclose(np.asarray(dk), np.asarray(dr), rtol=1e-6)
 
 
+def _placement_inputs(rng, n, m, radius, bn, shift=0.0):
+    """Sorted euclidean rows and queries in the kernels' padded layout.
+
+    alpha is the first coordinate, so rows ascend in alpha, and ``thresh``
+    is the half-norm test of |x - q| <= r.  ``radius`` is one value a query
+    row; NaN marks a row as padding (r = thresh = -BIG, matches nothing).
+    """
+    d = 6
+    x = rng.normal(size=(n, d)).astype(np.float32) + np.float32(shift)
+    x = x[np.argsort(x[:, 0], kind="stable")]
+    q = rng.normal(size=(m, d)).astype(np.float32)
+    r = np.asarray(radius, np.float32)
+    th = (r * r - (q * q).sum(1)) / np.float32(2)
+    pad = np.isnan(r)
+    r, th = np.where(pad, -ops.BIG, r), np.where(pad, -ops.BIG, th)
+    xs, al, hn, _, _ = ops.pad_database(x, x[:, 0], (x * x).sum(1) / 2, bn=bn)
+    qp, aqp, rp, thp, _ = ops.pad_queries(q, q[:, 0], r.astype(np.float32),
+                                          th.astype(np.float32), tq=128)
+    return (qp, aqp, rp, thp), (xs, al, hn)
+
+
+def _runs(idx, offsets, counts, bn):
+    """(first slot, last slot, length) of every (query row, db block) run."""
+    out = []
+    for o, c in zip(offsets, counts):
+        cols = np.asarray(idx[o:o + c])
+        for b in np.unique(cols // bn):
+            slots = o + np.flatnonzero(cols // bn == b)
+            out.append((int(slots[0]), int(slots[-1]), slots.size))
+    return out
+
+
+@pytest.mark.parametrize("case", ["lone_row", "group_edges", "tile_crossing",
+                                  "full_block", "empty_second_segment"])
+def test_compact_placement_matches_ref(case):
+    """Pass 2 places only the rows with survivors: every edge of the row
+    loop lands each run in the oracle's slots, slot for slot."""
+    from repro.kernels.snn_query import snn_compact_stacked
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(14)
+    tq, bn, n = 128, 128, 512
+    nan = np.float32("nan")
+    if case == "lone_row":  # one real row; pad_queries adds 127 padding rows
+        radius = [2.5]
+    elif case == "group_edges":  # survivors only in rows 7, 8 and tq - 1
+        radius = np.full(tq, nan)
+        radius[[7, 8, tq - 1]] = 2.5
+    else:  # one row that takes every db row, beside a padding row
+        radius = [50.0, nan]
+    qa, xa = _placement_inputs(rng, n, len(radius), radius, bn)
+    if case == "empty_second_segment":
+        _, xb = _placement_inputs(rng, n, 1, [1.0], bn, shift=100.0)
+        stack = tuple(jnp.stack([a, b]) for a, b in zip(xa, xb))
+        per = ref.snn_count_stacked_ref(*qa, *stack, n_seg=2)
+        assert int(per[0].sum()) == n and int(per[1].sum()) == 0
+        _, indptr, off = ref.stacked_prefix(per)
+        cap = ops.csr_capacity(int(indptr[-1]))
+        ik, dk = snn_compact_stacked(*qa, off, *stack, nnz=cap, tq=tq, bn=bn,
+                                     interpret=True)
+        ir, dr = ref.snn_compact_stacked_ref(*qa, off, *stack, n_seg=2,
+                                             nnz=cap)
+        counts, offsets = np.asarray(per[0]), np.asarray(off[0])
+    else:
+        counts = np.asarray(ref.snn_count_ref(*qa, *xa))
+        # the crossing case starts its row 4 slots short of the second
+        # 1,024-slot output tile, so its first block's run spans both
+        start = 1020 if case == "tile_crossing" else 0
+        offsets = start + np.concatenate([[0], np.cumsum(counts)[:-1]])
+        total = start + int(counts.sum())
+        offsets[counts == 0] = total  # padding rows: any slot below nnz
+        cap = ops.csr_capacity(total)
+        off = jnp.asarray(offsets.astype(np.int32))
+        ik, dk = snn_compact(*qa, off, *xa, nnz=cap, tq=tq, bn=bn,
+                             interpret=True)
+        ir, dr = ref.snn_compact_ref(*qa, off, *xa, nnz=cap)
+    assert np.asarray(ik).tolist() == np.asarray(ir).tolist()
+    np.testing.assert_allclose(np.asarray(dk), np.asarray(dr), rtol=1e-6)
+
+    # the case exercised the edge it names
+    runs = _runs(np.asarray(ik), offsets, counts, bn)
+    assert runs
+    active = np.flatnonzero(counts).tolist()
+    if case == "lone_row":
+        assert active == [0]
+    elif case == "group_edges":
+        assert active == [7, 8, tq - 1]
+    elif case == "tile_crossing":
+        assert any(a >> 10 != b >> 10 for a, b, _ in runs)
+    else:
+        assert any(c == bn for _, _, c in runs)
+
+
 def test_csr_capacity_bucketing():
     assert ops.csr_capacity(0) == 128
     assert ops.csr_capacity(127) == 128

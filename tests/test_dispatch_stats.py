@@ -10,9 +10,10 @@ stats — no increment can be lost, whatever the interleaving.
 import threading
 
 import numpy as np
+import pytest
 
 from repro.core import build_index, engine as _engine
-from repro.core.join import single_query
+from repro.core.join import query_counts, single_query
 
 
 def test_counters_thread_isolated_and_aggregated():
@@ -84,3 +85,43 @@ def test_concurrent_fused_serving_batches():
     for tid, snap in snaps.items():
         assert snap["kernel_launches"] >= 3, (tid, snap)
         assert snap["host_transfers"] >= 3, (tid, snap)
+
+
+def _brute_runs(index, csr, block):
+    """Distinct (query row, db block) pairs of a one-segment CSR result."""
+    pos = np.empty(index.order.size, np.int64)
+    pos[index.order] = np.arange(index.order.size)  # id -> sorted row
+    rows = np.repeat(np.arange(csr.m), np.diff(csr.indptr))
+    return len(set(zip(rows.tolist(), (pos[csr.indices] // block).tolist())))
+
+
+@pytest.mark.parametrize("lane", ["pallas-tpu", "pallas-gpu"])
+def test_placed_runs_counts_pass_two_runs(lane):
+    """``placed_runs`` is the (row, block) runs pass 2 placed: the brute
+    count on the exact classic call and on the fused one after it, and
+    nothing where pass 2 does not run."""
+    rng = np.random.default_rng(14)
+    x = rng.normal(size=(700, 5)).astype(np.float32)
+    index = build_index(x)
+    pack = _engine.pack_from_index(index, block=128)
+    q = rng.normal(size=(20, 5)).astype(np.float32)
+    for fused in (False, True):  # the classic path seeds the fused one
+        _engine.DISPATCH_STATS.reset()
+        csr = single_query(index, q, 1.2, pack=pack, use_pallas=lane,
+                           query_tile=64)
+        want = _brute_runs(index, csr, 128)
+        assert want > csr.m  # some rows span several blocks
+        snap = _engine.DISPATCH_STATS.snapshot()
+        assert snap["placed_runs"] == want, fused
+        assert snap["kernel_launches"] == (1 if fused else 3)
+
+    _engine.DISPATCH_STATS.reset()
+    counts = query_counts(index, q, 1.2, block=128, query_tile=64,
+                          use_pallas=lane)
+    assert counts.sum() == csr.nnz  # a count-only batch: pass 1 alone
+    far = (100.0 + q).astype(np.float32)  # no survivors: nothing placed
+    for _ in range(2):
+        assert single_query(index, far, 1.2, pack=pack, use_pallas=lane,
+                            query_tile=64).nnz == 0
+    snap = _engine.DISPATCH_STATS.snapshot()
+    assert snap["live_cells"] > 0 and snap["placed_runs"] == 0

@@ -93,7 +93,7 @@ def test_compact_over_the_ceiling_is_refused():
         snn_query._compact_outputs(2 * snn_query.MAX_NNZ, TQ, BN)
 
 
-@pytest.mark.parametrize("kernel", ["count", "compact"])
+@pytest.mark.parametrize("kernel", ["count", "compact", "compact_box"])
 def test_single_segment_kernels_compile(spec, kernel):
     xs, row = spec((N_PAD, D)), spec((N_PAD,))
     if kernel == "count":
@@ -103,12 +103,16 @@ def test_single_segment_kernels_compile(spec, kernel):
 
         _compile(fn, *_queries(spec), xs, row, row)
     else:
-        def fn(q, aq, r, th, off, xs, al, hn):
+        proj = ((spec((KE, M)), spec((KE, N_PAD))) if kernel == "compact_box"
+                else (None, None))
+
+        def fn(q, aq, r, th, off, xs, al, hn, pq, px):
             return snn_query.snn_compact(q, aq, r, th, off, xs, al, hn,
-                                         nnz=1 << 12, tq=TQ, bn=BN,
+                                         pq, px, nnz=1 << 12, tq=TQ, bn=BN,
                                          interpret=False)
 
-        _compile(fn, *_queries(spec), spec((M,), jnp.int32), xs, row, row)
+        _compile(fn, *_queries(spec), spec((M,), jnp.int32), xs, row, row,
+                 *proj)
 
 
 @pytest.mark.parametrize("n_seg,mixed", [(1, False), (2, True)])
