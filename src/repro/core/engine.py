@@ -53,8 +53,10 @@ Callers normally reach this module through `core.join`, the workload
 front-end layer: `join(A, B, r)` (and the point-query / self-join /
 reverse / count-only front-ends built on it) owns query-side scheduling —
 sorting A, chunking, permuting results back — and hands each chunk to
-`run_csr_packed` / `run_counts_packed` here.  The engine itself never
-reorders queries.
+`run_csr_packed` / `run_counts_packed` here.  The executors never reorder
+queries; the served entries (`query_csr_packed`, `core.join.query_counts`)
+may run a batch of several query tiles in alpha order (`TileOrder`) and
+put its rows back in the caller's order.
 """
 from __future__ import annotations
 
@@ -79,8 +81,8 @@ _REAL = _ops.BIG / 2
 # Dispatch instrumentation                                                     #
 # --------------------------------------------------------------------------- #
 _STAT_FIELDS = ("kernel_launches", "host_transfers", "jit_compiles",
-                "bytes_planned", "grid_cells", "live_cells", "placed_runs",
-                "knn_rounds", "knn_candidates", "knn_wanted")
+                "bytes_planned", "grid_cells", "live_cells", "arrival_cells",
+                "placed_runs", "knn_rounds", "knn_candidates", "knn_wanted")
 
 
 class _StatCounters:
@@ -113,6 +115,12 @@ class DispatchStats(threading.local):
     query tile, db block) cells of every stacked pass a device lane runs,
     and ``live_cells`` those whose sorted window the kernel finds hit
     (`_count_cells`): the rest skip the MXU but still fetch their blocks.
+    ``arrival_cells`` counts the live cells those passes would have run
+    with each batch's rows tiled in the order they arrived, with the same
+    pass multiplicity: it equals ``live_cells`` for a batch that kept its
+    order and exceeds it by what `TileOrder` saved (spanned as
+    ``snn.order``, with ``rows``, ``arrival_cells``, ``live_cells`` and
+    ``ordered``, inside the served entries' ``snn.prep``).
     ``placed_runs`` counts the (query row, db block) runs pass 2 placed
     (`_placed_runs`): the row visits of the compaction's row loop that do
     work, so over ``tq`` x the pass-2 live cells it is the share of a
@@ -124,8 +132,9 @@ class DispatchStats(threading.local):
 
     Readers: the on-chip benchmark's metrics (``bench/metrics``:
     ``launches_per_row`` reads ``kernel_launches``, ``live_cells`` and
-    ``knn_candidates`` read the fields of their names, all over the
-    measured window's `aggregate`; no metric reads ``placed_runs``, which
+    ``knn_candidates`` read the fields of their names, ``order_cells``
+    reads ``arrival_cells`` / ``live_cells``, all over the measured
+    window's `aggregate`; no metric reads ``placed_runs``, which
     the harness prints with the rest of the window's counters as
     "dispatch in the window"), `kernels.registry.compile_counts`
     beside ``jit_compiles``, the serving registry's memory budget
@@ -1067,38 +1076,60 @@ def _prologue(pack: SegmentPack, qp, aqp, rp, thp, m: int, query_tile: int,
     with TraceAnnotation("snn.prep"):
         if pack.segments:
             pack.memory_plan(int(qp.shape[0]), query_tile)
-        kq = 0
-        if pq is not None and pack.ke:
-            kq = min(pack.ke, int(np.asarray(pq).shape[0]))
-        pq_np = pq64 = qn64 = None
-        if kq:
-            pq_np = np.asarray(pq, np.float32)[:kq]
-            pq64 = pq_np[:, :m].astype(np.float64)
-            qn64 = _qnorm64(rp, thp, m)
-        aq64 = np.asarray(aqp, np.float64)[:m]
-        r64 = np.asarray(rp, np.float64)[:m]
-        mask = pack.live_mask(aq64, r64, pq64, qn64)
+        kq, pq_np, pq64, qn64, mask = _live_segments(pack, aqp, rp, thp, pq,
+                                                     m)
         if first_seg:
             mask[:first_seg] = False
     return kq, pq_np, pq64, qn64, np.nonzero(mask)[0]
+
+
+def _live_segments(pack: SegmentPack, aq, r, th, pq, m: int):
+    """(kq, pq_np, pq64, qn64, mask) for the ``m`` real rows of a batch in
+    any row order: the box prune's effective component count, its query
+    projections in float32 and float64 and the float64 query norms (all
+    None when the pack or the batch carries no extra components), and
+    which segments some row's window may hit (`SegmentPack.live_mask`)."""
+    kq = 0
+    if pq is not None and pack.ke:
+        kq = min(pack.ke, int(np.asarray(pq).shape[0]))
+    pq_np = pq64 = qn64 = None
+    if kq:
+        pq_np = np.asarray(pq, np.float32)[:kq]
+        pq64 = pq_np[:, :m].astype(np.float64)
+        qn64 = _qnorm64(r, th, m)
+    aq64 = np.asarray(aq, np.float64)[:m]
+    r64 = np.asarray(r, np.float64)[:m]
+    return kq, pq_np, pq64, qn64, pack.live_mask(aq64, r64, pq64, qn64)
 
 
 def _count_cells(pack: SegmentPack, live_idx: np.ndarray, aqp, rp, m: int,
                  query_tile: int) -> tuple[int, int]:
     """(grid cells, live cells) of ONE stacked pass over ``live_idx``.
 
-    The grid is (live segments x query tiles x db blocks).  A cell is live
-    when the kernel's `_window_hit` holds: some row of the tile has
-    ``aq + r >= a_lo`` and ``aq - r <= a_hi`` for the block's first and
-    last alpha, in the device arrays' float32.  Alphas ascend within a
-    segment, so both bounds ascend with the block and each row hits the
-    contiguous run of blocks ``[searchsorted(a_hi, aq - r),
+    The grid is (live segments x query tiles x db blocks); the live cells
+    are `_live_cells` of the ``m`` real rows (padding rows, r = -BIG, hit
+    no block).
+    """
+    nb = pack.block_bounds()[0].shape[1]
+    live = _live_cells(pack, live_idx, np.asarray(aqp)[:m],
+                       np.asarray(rp)[:m], query_tile)
+    return int(live_idx.size) * (int(aqp.shape[0]) // query_tile) * nb, live
+
+
+def _live_cells(pack: SegmentPack, live_idx: np.ndarray, aq: np.ndarray,
+                r: np.ndarray, query_tile: int) -> int:
+    """Live cells of ONE stacked pass over ``live_idx`` for the rows
+    ``aq`` / ``r`` (host float32), tiled in the order given.
+
+    A cell is live when the kernel's `_window_hit` holds: some row of the
+    tile has ``aq + r >= a_lo`` and ``aq - r <= a_hi`` for the block's
+    first and last alpha, in the device arrays' float32.  Alphas ascend
+    within a segment, so both bounds ascend with the block and each row
+    hits the contiguous run of blocks ``[searchsorted(a_hi, aq - r),
     searchsorted(a_lo, aq + r, "right"))``; a tile's live blocks are the
-    union of its rows' runs.  Padding rows (r = -BIG) hit no block, so
-    only the ``m`` real rows are read.  O(m log blocks) per segment.
+    union of its rows' runs.  O(m log blocks) per segment.
     """
     lo_b, hi_b = pack.block_bounds()
-    aq, r = np.asarray(aqp)[:m], np.asarray(rp)[:m]
     lo_q, hi_q = aq - r, aq + r
     nb = lo_b.shape[1]
     live = 0
@@ -1118,12 +1149,120 @@ def _count_cells(pack: SegmentPack, live_idx: np.ndarray, aqp, rp, m: int,
         live += int(e[0] - s[0]
                     + np.maximum(e[1:] - np.maximum(s[1:], reach[:-1]),
                                  0).sum())
-    return int(live_idx.size) * (int(aqp.shape[0]) // query_tile) * nb, live
+    return live
 
 
 def _note_cells(cells: tuple[int, int], passes: int) -> None:
     DISPATCH_STATS.grid_cells += passes * cells[0]
     DISPATCH_STATS.live_cells += passes * cells[1]
+    DISPATCH_STATS.arrival_cells += passes * cells[1]
+
+
+# --------------------------------------------------------------------------- #
+# Row order of a served batch                                                  #
+# --------------------------------------------------------------------------- #
+def indptr_from_counts(counts: np.ndarray) -> np.ndarray:
+    out = np.zeros(counts.size + 1, np.int64)
+    np.cumsum(counts, out=out[1:])
+    return out
+
+
+def permute_rows(indptr, indices, distances, dest):
+    """Reorder CSR rows: input row i becomes output row ``dest[i]``.
+
+    One O(nnz) gather; used to undo a query sort (``dest = index.order``
+    for the self-join, the alpha argsort for a bichromatic join or a
+    served batch) so public results are in the caller's original row order.
+    """
+    counts = np.diff(indptr)
+    counts_out = np.empty_like(counts)
+    counts_out[dest] = counts
+    out_indptr = indptr_from_counts(counts_out)
+    pos = np.repeat(out_indptr[:-1][dest] - indptr[:-1], counts) \
+        + np.arange(indices.size)
+    out_idx = np.empty_like(indices)
+    out_idx[pos] = indices
+    out_d = None
+    if distances is not None:
+        out_d = np.empty_like(distances)
+        out_d[pos] = distances
+    return out_indptr, out_idx, out_d
+
+
+class TileOrder:
+    """The order a served batch's rows run in, and its undoing.
+
+    A served batch is tiled in the order its rows arrived.  Where each
+    row's sorted window is narrow but the rows are scattered, as in a
+    DBSCAN client's block of region queries at low d, the union of a
+    tile's windows still covers most db blocks; in alpha order neighboring
+    windows share a tile.  For a batch of more than one query tile, both
+    orders' live cells of one pass are counted (`_live_cells`) over the
+    segments `_prologue` will run, and the alpha order (a stable argsort)
+    is taken when it saves at least one tile's worth of cells: the live
+    segments' db blocks.  A one-tile batch pays one comparison.  Each
+    row's results do not depend on the tile it runs in, so the rows put
+    back (`csr`, `counts`) are bit-identical to the arrival order's.
+
+    Spanned as ``snn.order`` (``rows``, ``arrival_cells``, ``live_cells``,
+    ``ordered``).  The executors add their live cells to
+    ``DISPATCH_STATS.arrival_cells`` (`_note_cells`); putting the rows back
+    adds what the order saved in each pass that ran since (this thread's
+    ``live_cells`` grew by passes x the sorted order's live cells).
+    """
+
+    def __init__(self, pack: SegmentPack, query_tile: int, aq: np.ndarray,
+                 r: np.ndarray, th: np.ndarray, pq):
+        self.perm = None            # None: the rows run as they arrived
+        m = int(aq.shape[0])
+        if m <= query_tile or not pack.segments:
+            return
+        with TraceAnnotation("snn.order", rows=m) as span:
+            live_idx = np.flatnonzero(_live_segments(pack, aq, r, th, pq,
+                                                     m)[-1])
+            perm = np.argsort(aq, kind="stable")
+            arrival = _live_cells(pack, live_idx, aq, r, query_tile)
+            live = _live_cells(pack, live_idx, aq[perm], r[perm], query_tile)
+            nb = pack.block_bounds()[0].shape[1]
+            ordered = bool(live_idx.size) and \
+                arrival - live >= live_idx.size * nb
+            span.set_metadata(arrival_cells=arrival,
+                              live_cells=live if ordered else arrival,
+                              ordered=int(ordered))
+        if ordered:
+            self.perm = perm
+            self._cells = (live, arrival)
+            self._live0 = DISPATCH_STATS.live_cells
+
+    def rows(self, xq, aq, r, th, pq):
+        """The batch's predicate arrays in run order (``pq``: (ke, m) or
+        None)."""
+        p = self.perm
+        if p is None:
+            return xq, aq, r, th, pq
+        return xq[p], aq[p], r[p], th[p], None if pq is None else pq[:, p]
+
+    def _note_saved(self) -> None:
+        live, arrival = self._cells
+        ran = DISPATCH_STATS.live_cells - self._live0
+        DISPATCH_STATS.arrival_cells += ran // live * (arrival - live)
+
+    def csr(self, indptr, counts, ids, dh):
+        """A run-order CSR result (`run_csr_packed`'s) in request order."""
+        if self.perm is None:
+            return indptr, counts, ids, dh
+        self._note_saved()
+        indptr, ids, dh = permute_rows(indptr, ids, dh, self.perm)
+        return indptr, np.diff(indptr), ids, dh
+
+    def counts(self, counts: np.ndarray) -> np.ndarray:
+        """Run-order counts (`run_counts_packed`'s) in request order."""
+        if self.perm is None:
+            return counts
+        self._note_saved()
+        out = np.empty_like(counts)
+        out[self.perm] = counts
+        return out
 
 
 def _placed_runs(indptr, fi, bn: int) -> int:
@@ -1948,22 +2087,27 @@ def query_csr_packed(
     Front-ends that own a long-lived index (streaming snapshots, serving
     generations, graph builds) build the pack once per epoch and route every
     query batch through here.  ``mixed``, ``bucket`` and the index-derived
-    box projections behave as in `query_csr`.
+    box projections behave as in `query_csr`.  A batch of several query
+    tiles may run in alpha order (`TileOrder`); its rows come back in the
+    caller's order.
     """
     from . import snn as _snn  # deferred: snn imports this module lazily too
 
     with TraceAnnotation("snn.prep"):
         xq, aq, r, th, qsq = _snn.prepare_query_predicates(index, q, radius)
         m = xq.shape[0]
-        qp, aqp, rp, thp, _ = _ops.pad_queries(xq, aq, r, th, tq=query_tile,
-                                               bucket=bucket)
         pq = _snn.query_extra_projections(index, xq)
+        order = TileOrder(pack, query_tile, aq, r, th, pq)
+        xo, aq, r, th, pq = order.rows(xq, aq, r, th, pq)
+        qp, aqp, rp, thp, _ = _ops.pad_queries(xo, aq, r, th, tq=query_tile,
+                                               bucket=bucket)
         pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
     indptr, counts, ids, dh = run_csr_packed(
         pack, qp, aqp, rp, thp, m, query_tile=query_tile,
         use_pallas=use_pallas, memory_budget_mb=memory_budget_mb,
         pq=pqp, mixed=mixed, compacted=compacted, fused=fused)
     with TraceAnnotation("snn.assemble"):
+        indptr, counts, ids, dh = order.csr(indptr, counts, ids, dh)
         return _snn.csr_finalize(index, indptr, ids, dh, xq, qsq, counts,
                                  return_distance, native)
 

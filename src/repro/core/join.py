@@ -46,39 +46,12 @@ from jax.profiler import TraceAnnotation
 from ..kernels import ops as _ops
 from . import engine as _engine
 from . import snn as _snn
+from .engine import indptr_from_counts, permute_rows
 
 
 # --------------------------------------------------------------------------- #
 # CSR plumbing                                                                 #
 # --------------------------------------------------------------------------- #
-def indptr_from_counts(counts: np.ndarray) -> np.ndarray:
-    out = np.zeros(counts.size + 1, np.int64)
-    np.cumsum(counts, out=out[1:])
-    return out
-
-
-def permute_rows(indptr, indices, distances, dest):
-    """Reorder CSR rows: input row i becomes output row ``dest[i]``.
-
-    One O(nnz) gather; used to undo a query sort (``dest = index.order``
-    for the self-join, the alpha argsort for a bichromatic join) so public
-    results are in the caller's original row order.
-    """
-    counts = np.diff(indptr)
-    counts_out = np.empty_like(counts)
-    counts_out[dest] = counts
-    out_indptr = indptr_from_counts(counts_out)
-    pos = np.repeat(out_indptr[:-1][dest] - indptr[:-1], counts) \
-        + np.arange(indices.size)
-    out_idx = np.empty_like(indices)
-    out_idx[pos] = indices
-    out_d = None
-    if distances is not None:
-        out_d = np.empty_like(distances)
-        out_d[pos] = distances
-    return out_indptr, out_idx, out_d
-
-
 def transpose_csr(indptr, cols, dists, n_cols: int):
     """Exact CSR transpose: (rows -> cols) becomes (cols -> rows).
 
@@ -335,10 +308,13 @@ def single_query(index, q, radius, return_distance: bool = True, *,
     """A point-query batch is a bichromatic join whose A side is one chunk.
 
     This is the front-end `snn.query_radius_csr` and the streaming index
-    delegate to: no chunk loop, no query sort (a serving batch has no
-    exploitable order), just the engine's packed (or looped) executor over
-    a prebuilt ``pack`` (or ``segments``) — bit-identical to the historical
-    direct calls by construction, because these ARE those calls.
+    delegate to: no chunk loop, just the engine's packed (or looped)
+    executor over a prebuilt ``pack`` (or ``segments``) — bit-identical to
+    the historical direct calls by construction, because these ARE those
+    calls.  A serving batch can have an exploitable order: a client's block
+    of region queries scatters narrow windows over many query tiles, and
+    the packed entry (`engine.query_csr_packed`) then runs it in alpha
+    order (`engine.TileOrder`) and returns its rows in the caller's order.
     """
     if packed:
         if pack is None:
@@ -395,22 +371,23 @@ def query_counts(index, q, radius, *, block: int = 512,
 
     ``index`` is an `snn.SNNIndex` or a `streaming.StreamingSNNIndex`
     (counts run over base + deltas through the cached plan); ``radius`` is
-    a scalar or per-query (m,) vector in the native metric.
+    a scalar or per-query (m,) vector in the native metric.  A batch of
+    several query tiles may run in alpha order (`engine.TileOrder`); the
+    counts come back in request order.
     """
     with TraceAnnotation("snn.prep"):
         owner, pack = _resolve_pack(index, block)
-        xq, aq, r32, th, qsq = _snn.prepare_query_predicates(owner, q,
-                                                             radius)
+        xq, aq, r32, th, _ = _snn.prepare_query_predicates(owner, q, radius)
+        pq = _snn.query_extra_projections(owner, xq)
+        order = _engine.TileOrder(pack, query_tile, aq, r32, th, pq)
+        xq, aq, r32, th, pq = order.rows(xq, aq, r32, th, pq)
         qp, aqp, rp, thp, m = _ops.pad_queries(xq, aq, r32, th,
                                                tq=query_tile, bucket=bucket)
-        pq = _snn.query_extra_projections(owner, xq)
         pqp = None if pq is None else _ops.pad_components(pq, qp.shape[0])
-    return _engine.run_counts_packed(pack, qp, aqp, rp, thp, m,
-                                     query_tile=query_tile,
-                                     use_pallas=use_pallas,
-                                     memory_budget_mb=memory_budget_mb,
-                                     pq=pqp, mixed=mixed,
-                                     compacted=compacted)
+    return order.counts(_engine.run_counts_packed(
+        pack, qp, aqp, rp, thp, m, query_tile=query_tile,
+        use_pallas=use_pallas, memory_budget_mb=memory_budget_mb, pq=pqp,
+        mixed=mixed, compacted=compacted))
 
 
 # --------------------------------------------------------------------------- #
