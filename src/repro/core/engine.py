@@ -81,8 +81,9 @@ _REAL = _ops.BIG / 2
 # Dispatch instrumentation                                                     #
 # --------------------------------------------------------------------------- #
 _STAT_FIELDS = ("kernel_launches", "host_transfers", "jit_compiles",
-                "bytes_planned", "grid_cells", "live_cells", "arrival_cells",
-                "placed_runs", "knn_rounds", "knn_candidates", "knn_wanted")
+                "bytes_planned", "grid_cells", "live_cells", "fetched_cells",
+                "arrival_cells", "placed_runs", "knn_rounds", "knn_candidates",
+                "knn_wanted")
 
 
 class _StatCounters:
@@ -114,7 +115,10 @@ class DispatchStats(threading.local):
     (pack epoch, query bucket)).  ``grid_cells`` counts the (live segment,
     query tile, db block) cells of every stacked pass a device lane runs,
     and ``live_cells`` those whose sorted window the kernel finds hit
-    (`_count_cells`): the rest skip the MXU but still fetch their blocks.
+    (`_count_cells`): the rest skip the MXU.  ``fetched_cells`` counts the
+    cells whose row block the pass fetches: each (segment, tile)'s live
+    block range (`kernels.snn_query.block_ranges`), or one block for a
+    tile that hits nothing; the pruned cells outside it fetch nothing.
     ``arrival_cells`` counts the live cells those passes would have run
     with each batch's rows tiled in the order they arrived, with the same
     pass multiplicity: it equals ``live_cells`` for a batch that kept its
@@ -131,12 +135,13 @@ class DispatchStats(threading.local):
     final pass's excess (`core.knn.query_knn`).
 
     Readers: the on-chip benchmark's metrics (``bench/metrics``:
-    ``launches_per_row`` reads ``kernel_launches``, ``live_cells`` and
-    ``knn_candidates`` read the fields of their names, ``order_cells``
-    reads ``arrival_cells`` / ``live_cells``, all over the measured
-    window's `aggregate`; no metric reads ``placed_runs``, which
-    the harness prints with the rest of the window's counters as
-    "dispatch in the window"), `kernels.registry.compile_counts`
+    ``launches_per_row`` reads ``kernel_launches``, ``live_cells``,
+    ``fetched_cells`` and ``knn_candidates`` read the fields of their
+    names, ``order_cells`` reads ``arrival_cells`` / ``live_cells``, all
+    over the measured window's `aggregate`; no metric reads
+    ``placed_runs``, which the harness prints with the rest of the
+    window's counters as "dispatch in the window"),
+    `kernels.registry.compile_counts`
     beside ``jit_compiles``, the serving registry's memory budget
     (``bytes_planned``), `benchmarks.common.dispatch_counts` and
     ``benchmarks/check_dispatch.py`` (launches and transfers per call), and
@@ -1103,23 +1108,28 @@ def _live_segments(pack: SegmentPack, aq, r, th, pq, m: int):
 
 
 def _count_cells(pack: SegmentPack, live_idx: np.ndarray, aqp, rp, m: int,
-                 query_tile: int) -> tuple[int, int]:
-    """(grid cells, live cells) of ONE stacked pass over ``live_idx``.
+                 query_tile: int) -> tuple[int, int, int]:
+    """(grid cells, live cells, fetched cells) of ONE stacked pass over
+    ``live_idx``.
 
-    The grid is (live segments x query tiles x db blocks); the live cells
-    are `_live_cells` of the ``m`` real rows (padding rows, r = -BIG, hit
-    no block).
+    The grid is (live segments x query tiles x db blocks); the live and
+    fetched cells are `_live_cells` of the ``m`` real rows (padding rows,
+    r = -BIG, hit no block), plus one fetched block for each tile of
+    padding rows alone.
     """
     nb = pack.block_bounds()[0].shape[1]
-    live = _live_cells(pack, live_idx, np.asarray(aqp)[:m],
-                       np.asarray(rp)[:m], query_tile)
-    return int(live_idx.size) * (int(aqp.shape[0]) // query_tile) * nb, live
+    n_tiles = int(aqp.shape[0]) // query_tile
+    live, fetched = _live_cells(pack, live_idx, np.asarray(aqp)[:m],
+                                np.asarray(rp)[:m], query_tile)
+    pad_tiles = n_tiles - -(-m // query_tile)
+    return (int(live_idx.size) * n_tiles * nb, live,
+            fetched + int(live_idx.size) * pad_tiles)
 
 
 def _live_cells(pack: SegmentPack, live_idx: np.ndarray, aq: np.ndarray,
-                r: np.ndarray, query_tile: int) -> int:
-    """Live cells of ONE stacked pass over ``live_idx`` for the rows
-    ``aq`` / ``r`` (host float32), tiled in the order given.
+                r: np.ndarray, query_tile: int) -> tuple[int, int]:
+    """(live, fetched) cells of ONE stacked pass over ``live_idx`` for the
+    rows ``aq`` / ``r`` (host float32), tiled in the order given.
 
     A cell is live when the kernel's `_window_hit` holds: some row of the
     tile has ``aq + r >= a_lo`` and ``aq - r <= a_hi`` for the block's
@@ -1127,21 +1137,32 @@ def _live_cells(pack: SegmentPack, live_idx: np.ndarray, aq: np.ndarray,
     within a segment, so both bounds ascend with the block and each row
     hits the contiguous run of blocks ``[searchsorted(a_hi, aq - r),
     searchsorted(a_lo, aq + r, "right"))``; a tile's live blocks are the
-    union of its rows' runs.  O(m log blocks) per segment.
+    union of its rows' runs.  The blocks a tile fetches span them, from
+    the first run's start to the last run's end, as the kernels' clamped
+    index maps fetch (`kernels.snn_query.block_ranges`); a tile whose
+    rows hit nothing fetches one.  O(m log blocks) per segment.
     """
     lo_b, hi_b = pack.block_bounds()
     lo_q, hi_q = aq - r, aq + r
     nb = lo_b.shape[1]
-    live = 0
+    n_tiles = -(-aq.shape[0] // query_tile)
+    live = fetched = 0
     for k in live_idx:
         first = np.searchsorted(hi_b[k], lo_q, side="left")
         last = np.searchsorted(lo_b[k], hi_q, side="right")
         rows = np.flatnonzero(first < last)
         if not rows.size:
+            fetched += n_tiles
             continue
+        # rows ascend, so each tile's rows with runs lie together
+        tile = rows // query_tile
+        starts = np.flatnonzero(np.r_[True, tile[1:] != tile[:-1]])
+        fetched += int((np.maximum.reduceat(last[rows], starts)
+                        - np.minimum.reduceat(first[rows], starts)).sum())
+        fetched += n_tiles - starts.size
         # tile t's runs shifted by t * nb stay apart from other tiles', so
         # the union of all shifted runs sums the tiles' unions
-        off = (rows // query_tile) * nb
+        off = tile * nb
         s, e = first[rows] + off, last[rows] + off
         order = np.argsort(s)
         s, e = s[order], e[order]
@@ -1149,13 +1170,15 @@ def _live_cells(pack: SegmentPack, live_idx: np.ndarray, aq: np.ndarray,
         live += int(e[0] - s[0]
                     + np.maximum(e[1:] - np.maximum(s[1:], reach[:-1]),
                                  0).sum())
-    return live
+    return live, fetched
 
 
-def _note_cells(cells: tuple[int, int], passes: int) -> None:
-    DISPATCH_STATS.grid_cells += passes * cells[0]
-    DISPATCH_STATS.live_cells += passes * cells[1]
-    DISPATCH_STATS.arrival_cells += passes * cells[1]
+def _note_cells(cells: tuple[int, int, int], passes: int) -> None:
+    grid, live, fetched = cells
+    DISPATCH_STATS.grid_cells += passes * grid
+    DISPATCH_STATS.live_cells += passes * live
+    DISPATCH_STATS.fetched_cells += passes * fetched
+    DISPATCH_STATS.arrival_cells += passes * live
 
 
 # --------------------------------------------------------------------------- #
@@ -1205,10 +1228,12 @@ class TileOrder:
     back (`csr`, `counts`) are bit-identical to the arrival order's.
 
     Spanned as ``snn.order`` (``rows``, ``arrival_cells``, ``live_cells``,
-    ``ordered``).  The executors add their live cells to
-    ``DISPATCH_STATS.arrival_cells`` (`_note_cells`); putting the rows back
-    adds what the order saved in each pass that ran since (this thread's
-    ``live_cells`` grew by passes x the sorted order's live cells).
+    ``fetched``: the cells of one pass that fetch their blocks in the
+    order taken, over the batch's own tiles, and ``ordered``).  The
+    executors add their live cells to ``DISPATCH_STATS.arrival_cells``
+    (`_note_cells`); putting the rows back adds what the order saved in
+    each pass that ran since (this thread's ``live_cells`` grew by passes
+    x the sorted order's live cells).
     """
 
     def __init__(self, pack: SegmentPack, query_tile: int, aq: np.ndarray,
@@ -1221,14 +1246,18 @@ class TileOrder:
             live_idx = np.flatnonzero(_live_segments(pack, aq, r, th, pq,
                                                      m)[-1])
             perm = np.argsort(aq, kind="stable")
-            arrival = _live_cells(pack, live_idx, aq, r, query_tile)
-            live = _live_cells(pack, live_idx, aq[perm], r[perm], query_tile)
+            arrival, fetched = _live_cells(pack, live_idx, aq, r,
+                                           query_tile)
+            live, sorted_fetched = _live_cells(pack, live_idx, aq[perm],
+                                               r[perm], query_tile)
             nb = pack.block_bounds()[0].shape[1]
             ordered = bool(live_idx.size) and \
                 arrival - live >= live_idx.size * nb
+            if ordered:
+                fetched = sorted_fetched
             span.set_metadata(arrival_cells=arrival,
                               live_cells=live if ordered else arrival,
-                              ordered=int(ordered))
+                              fetched=fetched, ordered=int(ordered))
         if ordered:
             self.perm = perm
             self._cells = (live, arrival)

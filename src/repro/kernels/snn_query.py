@@ -45,6 +45,11 @@ Five entry kernels share the body:
     *stack* of segments at once (`core.engine.SegmentPack`) — the grid grows a
     leading segment axis, so one launch covers every live segment of a
     multi-segment index instead of one launch (plus host sync) per segment.
+    Each (segment, query tile) also gets its live block range
+    (`block_ranges`), scalar-prefetched: the row-block index maps clamp
+    into it, so a pruned cell outside the range asks for the block the
+    step before it held and the pipeline fetches nothing — it skips its
+    fetch as well as its matmul, and costs the grid step alone.
 
 Layout notes (TPU): 1-D per-row arrays (alpha, half-norm, per-query scalars)
 are carried as (1, n)/(1, m) so the last dim is the 128-lane axis; stacked
@@ -210,28 +215,43 @@ def _count_kernel(mix, q_ref, aq_ref, r_ref, th_ref, x_ref, al_ref, hn_ref,
         out_ref[...] += cnt[None, :]
 
 
-def _count_stacked_kernel(mix, q_ref, aq_ref, r_ref, th_ref, x_ref, al_ref,
-                          hn_ref, *rest):
+def _stacked_hit(rng_ref, aq_ref, r_ref, al_ref, body):
+    """Run ``body`` on a stacked cell that `_window_hit` finds hit.
+
+    A cell outside its tile's block range (`block_ranges`, prefetched into
+    SMEM as ``rng_ref``) holds the clamped block's alphas, which may hit,
+    so the range test comes first: it is the whole cost of such a cell.
+    Inside the range the test is `_window_hit` as in the 2-D kernels, so
+    the cells that run ``body`` are those the window test finds hit.
+    """
+    si, qi, bi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    k = 2 * (si * pl.num_programs(1) + qi)
+
+    @pl.when((rng_ref[k] <= bi) & (bi <= rng_ref[k + 1]))
+    def _():
+        a_lo = al_ref[0, 0, 0]
+        a_hi = al_ref[0, 0, al_ref.shape[2] - 1]
+        pl.when(_window_hit(aq_ref[0, :], r_ref[0, :], a_lo, a_hi))(body)
+
+
+def _count_stacked_kernel(mix, rng_ref, q_ref, aq_ref, r_ref, th_ref, x_ref,
+                          al_ref, hn_ref, *rest):
     """`_count_kernel` with a leading segment grid axis over stacked tensors."""
     pq_ref, px_ref, (out_ref,) = _split_rest(rest, 1)
-    bi = pl.program_id(2)
 
-    @pl.when(bi == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a_lo = al_ref[0, 0, 0]
-    a_hi = al_ref[0, 0, al_ref.shape[2] - 1]
-    hit = _window_hit(aq_ref[0, :], r_ref[0, :], a_lo, a_hi)
-
-    @pl.when(hit)
-    def _():
+    def body():
         cnt = _count_tile(
             q_ref[...], aq_ref[...], r_ref[...], th_ref[...], x_ref[0],
             al_ref[0], hn_ref[0],
             None if pq_ref is None else pq_ref[...],
             None if px_ref is None else px_ref[0], mix)
         out_ref[0] += cnt[None, :]
+
+    _stacked_hit(rng_ref, aq_ref, r_ref, al_ref, body)
 
 
 def _grid_specs(m, n, d, tq, bn, ke=0):
@@ -540,24 +560,84 @@ def snn_compact(q, aq, r, thresh, offsets, xs, alphas, half_norms,
 # --------------------------------------------------------------------------- #
 # Stacked-grid variants (one launch over a whole SegmentPack)                  #
 # --------------------------------------------------------------------------- #
-def _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke=0):
-    """Grid + input specs over a stack; per-row stacks arrive as (S, 1, n)."""
+def _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke=0, block=None):
+    """Grid + input specs over a stack; per-row stacks arrive as (S, 1, n).
+
+    ``block(s, qi, bi, *prefetch)`` picks the db block a cell's row-block
+    operands (xs, alphas, half-norms, px) come from; by default ``bi``.
+    Every index map passes scalar-prefetch refs through, if there are any.
+    """
+    if block is None:
+        block = lambda s, qi, bi, *_: bi  # noqa: E731
     grid = (n_seg, m // tq, n // bn)
     in_specs = [
-        pl.BlockSpec((tq, d), lambda s, qi, bi: (qi, 0)),      # q
-        pl.BlockSpec((1, tq), lambda s, qi, bi: (0, qi)),      # aq
-        pl.BlockSpec((1, tq), lambda s, qi, bi: (0, qi)),      # r
-        pl.BlockSpec((1, tq), lambda s, qi, bi: (0, qi)),      # thresh
-        pl.BlockSpec((1, bn, d), lambda s, qi, bi: (s, bi, 0)),  # xs stack
-        pl.BlockSpec((1, 1, bn), lambda s, qi, bi: (s, 0, bi)),  # alpha stack
-        pl.BlockSpec((1, 1, bn), lambda s, qi, bi: (s, 0, bi)),  # half-norms
+        pl.BlockSpec((tq, d), lambda s, qi, bi, *_: (qi, 0)),      # q
+        pl.BlockSpec((1, tq), lambda s, qi, bi, *_: (0, qi)),      # aq
+        pl.BlockSpec((1, tq), lambda s, qi, bi, *_: (0, qi)),      # r
+        pl.BlockSpec((1, tq), lambda s, qi, bi, *_: (0, qi)),      # thresh
+        pl.BlockSpec((1, bn, d),                                   # xs stack
+                     lambda s, qi, bi, *p: (s, block(s, qi, bi, *p), 0)),
+        pl.BlockSpec((1, 1, bn),                                   # alphas
+                     lambda s, qi, bi, *p: (s, 0, block(s, qi, bi, *p))),
+        pl.BlockSpec((1, 1, bn),                                   # half-norms
+                     lambda s, qi, bi, *p: (s, 0, block(s, qi, bi, *p))),
     ]
     if ke:
         in_specs += [
-            pl.BlockSpec((ke, tq), lambda s, qi, bi: (0, qi)),       # pq
-            pl.BlockSpec((1, ke, bn), lambda s, qi, bi: (s, 0, bi)),  # px stack
+            pl.BlockSpec((ke, tq), lambda s, qi, bi, *_: (0, qi)),     # pq
+            pl.BlockSpec((1, ke, bn),                                  # px stack
+                         lambda s, qi, bi, *p: (s, 0, block(s, qi, bi, *p))),
         ]
     return grid, in_specs
+
+
+def block_ranges(aq, r, alphas, *, tq: int, bn: int):
+    """Each (segment, query tile)'s live db block range of a stacked grid.
+
+    Returns (lo, hi), both (S, m // tq) int32: the first and the last block
+    that some row of the tile hits under `_window_hit`, in the same float32
+    expressions (``aq + r >= a_lo``, ``aq - r <= a_hi`` for the block's
+    first and last alpha).  Alphas ascend within a segment, so a row hits
+    the one run of blocks [first, last): ``first`` counts the blocks whose
+    last alpha lies below ``aq - r``, ``last`` those whose first alpha is
+    at most ``aq + r`` (binary searches, O(S m log nb)).  A tile's range
+    spans its rows' runs, so it holds every cell the kernels find hit, and
+    both its ends are hit.  A tile whose rows hit nothing (padding rows,
+    r = -BIG, never do) gets the empty range (nb, nb - 1), whose clamped
+    index maps fetch one block.
+    """
+    n_seg, n = alphas.shape
+    nb = n // bn
+    # strided slices read the block ends alone (no relayout of the stack)
+    a_lo = jax.lax.slice(alphas, (0, 0), (n_seg, n), (1, bn))
+    a_hi = jax.lax.slice(alphas, (0, bn - 1), (n_seg, n), (1, bn))
+    search = jax.vmap(jnp.searchsorted, in_axes=(0, None, None))
+    first = search(a_hi, aq - r, "left").astype(jnp.int32)
+    last = search(a_lo, aq + r, "right").astype(jnp.int32)
+    run = first < last
+    tiles = (n_seg, aq.shape[0] // tq, tq)
+    lo = jnp.min(jnp.where(run, first, nb).reshape(tiles), axis=2)
+    hi = jnp.max(jnp.where(run, last - 1, -1).reshape(tiles), axis=2)
+    return lo, jnp.where(lo > hi, nb - 1, hi)
+
+
+def _ranged_grid(n_seg, m, n, d, tq, bn, ke, aq, r, alphas):
+    """(grid, in_specs, range table) of a stacked kernel whose row-block
+    index maps clamp each cell's block into its tile's `block_ranges`.
+
+    The table is the flat (lo, hi) pairs of every (segment, tile), the
+    kernel's one scalar-prefetch operand.  Consecutive cells outside the
+    range map to the same block, so the pipeline issues no DMA for them.
+    """
+    n_q = m // tq
+
+    def block(s, qi, bi, rng):
+        k = 2 * (s * n_q + qi)
+        return jnp.minimum(jnp.maximum(bi, rng[k]), rng[k + 1])
+
+    grid, in_specs = _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke, block)
+    lo, hi = block_ranges(aq, r, alphas, tq=tq, bn=bn)
+    return grid, in_specs, jnp.stack([lo, hi], axis=-1).reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "bn", "interpret", "mixed"))
@@ -569,35 +649,40 @@ def snn_count_stacked(q, aq, r, thresh, xs, alphas, half_norms,
 
     ``xs`` is a (S, n_pad, d) stack of padded segments (`core.engine.
     SegmentPack`); ``alphas``/``half_norms`` are the matching (S, n_pad)
-    stacks and ``px`` the (S, ke, n_pad) projection stack.  Per-cell block
-    pruning is unchanged — a segment whose alpha range misses every query
-    window in the tile skips its MXU work — so stacking costs no extra
-    predicate evaluations, only the per-launch dispatch that the looped
-    engine paid S times.
+    stacks, each segment's alphas ascending, and ``px`` the (S, ke, n_pad)
+    projection stack.  Per-cell block pruning is unchanged — a segment
+    whose alpha range misses every query window in the tile skips its MXU
+    work — so stacking costs no extra predicate evaluations, only the
+    per-launch dispatch that the looped engine paid S times.  Cells outside
+    their tile's `block_ranges` fetch no block either.
     """
     m, d = q.shape
     n_seg, n, _ = xs.shape
     ke = 0 if pq is None else pq.shape[0]
-    grid, in_specs = _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke)
+    grid, in_specs, ranges = _ranged_grid(n_seg, m, n, d, tq, bn, ke,
+                                          aq, r, alphas)
     args = (q, aq[None, :], r[None, :], thresh[None, :], xs,
             alphas[:, None, :], half_norms[:, None, :])
     if ke:
         args += (pq, px)
     out = pl.pallas_call(
         functools.partial(_count_stacked_kernel, mixed),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, tq), lambda s, qi, bi: (s, 0, qi)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, tq),
+                                   lambda s, qi, bi, _: (s, 0, qi))),
         out_shape=jax.ShapeDtypeStruct((n_seg, 1, m), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
-    )(*args)
+    )(ranges, *args)
     return out[:, 0, :]
 
 
-def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
+def _compact_stacked_kernel(rng_ref, q_ref, aq_ref, r_ref, th_ref, off_ref,
                             x_ref, al_ref, hn_ref, *rest):
     """`_compact_kernel` with a leading segment grid axis.
 
@@ -622,12 +707,7 @@ def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
     def _():
         pltpu.sync_copy(off_ref.at[0], base_smem)
 
-    a_lo = al_ref[0, 0, 0]
-    a_hi = al_ref[0, 0, al_ref.shape[2] - 1]
-    hit = _window_hit(aq_ref[0, :], r_ref[0, :], a_lo, a_hi)
-
-    @pl.when(hit)
-    def _():
+    def body():
         keep, dhalf = _tile_body(
             q_ref[...], aq_ref[...], r_ref[...], th_ref[...], x_ref[0],
             al_ref[0], hn_ref[0],
@@ -635,6 +715,8 @@ def _compact_stacked_kernel(q_ref, aq_ref, r_ref, th_ref, off_ref,
             None if px_ref is None else px_ref[0])
         _scatter_cell(keep, dhalf, si * n_pad + bi * bn, base_smem, idx_ref,
                       dh_ref, *scr)
+
+    _stacked_hit(rng_ref, aq_ref, r_ref, al_ref, body)
 
 
 @functools.partial(jax.jit, static_argnames=("nnz", "tq", "bn", "interpret"))
@@ -651,32 +733,37 @@ def snn_compact_stacked(q, aq, r, thresh, offsets, xs, alphas, half_norms,
     `snn_compact`.
     All three grid dims are sequential: every cell scatters into the same
     flat output block, with the SMEM write bases carrying each query's
-    running write position across a segment's db blocks.
+    running write position across a segment's db blocks.  As in
+    `snn_count_stacked`, cells outside their tile's `block_ranges` fetch
+    no block.
     """
     m, d = q.shape
     n_seg, n, _ = xs.shape
     ke = 0 if pq is None else pq.shape[0]
-    grid, in_specs = _stacked_grid_specs(n_seg, m, n, d, tq, bn, ke)
+    grid, in_specs, ranges = _ranged_grid(n_seg, m, n, d, tq, bn, ke,
+                                          aq, r, alphas)
     in_specs = in_specs[:4] \
-        + [pl.BlockSpec((1, 1, tq), lambda s, qi, bi: (s, 0, qi))] \
+        + [pl.BlockSpec((1, 1, tq), lambda s, qi, bi, _: (s, 0, qi))] \
         + in_specs[4:]
     args = (q, aq[None, :], r[None, :], thresh[None, :], offsets[:, None, :],
             xs, alphas[:, None, :], half_norms[:, None, :])
     if ke:
         args += (pq, px)
     out_shape, scratch, vmem = _compact_outputs(nnz, tq, bn)
-    whole = pl.BlockSpec(out_shape[0].shape, lambda s, qi, bi: (0, 0, 0))
+    whole = pl.BlockSpec(out_shape[0].shape, lambda s, qi, bi, _: (0, 0, 0))
     out_idx, out_dh = pl.pallas_call(
         _compact_stacked_kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[whole, whole],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[whole, whole],
+            scratch_shapes=scratch),
         out_shape=out_shape,
-        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY,
                                  pltpu.ARBITRARY),
             vmem_limit_bytes=vmem),
         interpret=interpret,
-    )(*args)
+    )(ranges, *args)
     return _flat(out_idx, out_dh, nnz)

@@ -125,3 +125,84 @@ def test_placed_runs_counts_pass_two_runs(lane):
                             query_tile=64).nnz == 0
     snap = _engine.DISPATCH_STATS.snapshot()
     assert snap["live_cells"] > 0 and snap["placed_runs"] == 0
+
+
+def _brute_fetched(pack, aq, r, n_tiles, tq):
+    """Fetched cells of one pass, recounted from every (segment, tile,
+    block) window test in numpy float32: the span of each tile's hit
+    blocks, or one block where it hits none (padding tiles included)."""
+    lo_b, hi_b = pack.block_bounds()
+    hq, lq = aq + r, aq - r
+    fetched = 0
+    for s in range(lo_b.shape[0]):
+        for t in range(n_tiles):
+            rows = slice(t * tq, (t + 1) * tq)
+            hit = ((hq[rows, None] >= lo_b[s][None, :])
+                   & (lq[rows, None] <= hi_b[s][None, :])).any(axis=0)
+            live = np.flatnonzero(hit)
+            fetched += live[-1] - live[0] + 1 if live.size else 1
+    return int(fetched)
+
+
+def test_fetched_cells_recount_the_tiles_ranges():
+    """``fetched_cells`` lies between the live and the grid cells, and
+    equals a brute recount of the tiles' block ranges, in each pass."""
+    from repro.core import snn as _snn
+
+    rng = np.random.default_rng(4)
+    x = rng.normal(size=(3000, 4)).astype(np.float32)
+    index = build_index(x)
+    pack = _engine.pack_from_index(index, block=128)
+    q = x[rng.permutation(3000)[:300]] + np.float32(0.01)
+    _, aq, r, _, _ = _snn.prepare_query_predicates(index, q, 0.2)
+    order = np.argsort(aq, kind="stable")   # tiles in alpha order already
+    q, aq, r = q[order], aq[order], r[order]
+    tq, nb = 64, pack.block_bounds()[0].shape[1]
+    _engine.DISPATCH_STATS.reset()
+    csr = single_query(index, q, 0.2, pack=pack, use_pallas="pallas-tpu",
+                       query_tile=tq)
+    assert csr.nnz > 0
+    snap = _engine.DISPATCH_STATS.snapshot()
+    assert 0 < snap["live_cells"] <= snap["fetched_cells"] \
+        < snap["grid_cells"]
+    n_tiles = snap["grid_cells"] // (2 * pack.n_segments * nb)  # two passes
+    pad = n_tiles * tq - aq.size
+    aqp = np.pad(aq, (0, pad))
+    rp = np.pad(r, (0, pad), constant_values=-np.float32(_engine._ops.BIG))
+    assert snap["fetched_cells"] == 2 * _brute_fetched(pack, aqp, rp,
+                                                       n_tiles, tq)
+
+
+def test_all_live_d128_batch_fetches_every_cell():
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(2000, 128)).astype(np.float32)
+    index = build_index(x)
+    pack = _engine.pack_from_index(index, block=512)
+    q = rng.normal(size=(256, 128)).astype(np.float32)
+    _engine.DISPATCH_STATS.reset()
+    single_query(index, q, 16.0, pack=pack, use_pallas="pallas-tpu")
+    snap = _engine.DISPATCH_STATS.snapshot()
+    assert snap["live_cells"] == snap["fetched_cells"] \
+        == snap["grid_cells"] > 0
+
+
+def test_fetched_cells_reader():
+    """The benchmark's ``fetched_cells`` reader: fetched / grid in %, and
+    nothing from a program without the counter."""
+    import importlib.util
+    import os
+    from types import SimpleNamespace
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "metrics", "fetched_cells.py")
+    spec = importlib.util.spec_from_file_location("fetched_cells_reader",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+
+    def read(**dispatch):
+        return mod.read(SimpleNamespace(dispatch=dispatch))
+
+    assert read(grid_cells=400, live_cells=40, fetched_cells=50) == 12.5
+    assert read(grid_cells=400, live_cells=40) is None
+    assert read(grid_cells=0, live_cells=0, fetched_cells=0) is None

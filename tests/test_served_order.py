@@ -194,10 +194,10 @@ def test_order_counts_only_the_segments_that_run(corpus):
     (run,) = np.flatnonzero(mask)
     (dropped,) = np.flatnonzero(~mask)
     order = np.argsort(aq, kind="stable")
-    assert _engine._live_cells(pack, [dropped], aq, r, 128) > 0
+    assert _engine._live_cells(pack, [dropped], aq, r, 128)[0] > 0
 
     def one_pass(idx, rows):
-        return _engine._live_cells(pack, [idx], aq[rows], r[rows], 128)
+        return _engine._live_cells(pack, [idx], aq[rows], r[rows], 128)[0]
 
     arrival = one_pass(run, np.arange(BLOCK))
     _engine.DISPATCH_STATS.reset()

@@ -126,3 +126,20 @@ def test_fused_chain_compiles(spec, monkeypatch, n_seg, mixed):
                                             mixed)
     (xs, al, hn), (pq, px) = _stack(spec, n_seg, True)
     _compile(fn, *_queries(spec), xs, al, hn, pq, px)
+
+
+def test_block_ranges_read_the_block_ends_alone(spec):
+    """At the DBSCAN cell's shape (2,048 query rows, 7,521 blocks of 512)
+    the range table reads two strided slices of the alpha stack: no
+    instruction makes a stack-sized array (a relayout of 15 MB a launch)."""
+    n = 7521 * BN
+    v = spec((2048,))
+
+    def fn(aq, r, al):
+        return snn_query.block_ranges(aq, r, al, tq=TQ, bn=BN)
+
+    text = jax.jit(fn).lower(v, v, spec((1, n))).compile().as_text()
+    made = [line for line in text.splitlines()
+            if f"{n}]" in line.split("=", 1)[-1].split("(", 1)[0]
+            and "parameter(" not in line]
+    assert not made, made[:3]
